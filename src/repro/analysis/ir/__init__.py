@@ -30,8 +30,7 @@ from .jaxpr_checks import (DEFAULT_CONST_THRESHOLD, HOST_CALLBACK_PRIMS,
 from .pallas_lint import (PallasCallCapture, check_capture,
                           intercept_pallas_calls, lint_pallas_kernels)
 from .retrace import RetraceSentinel
-from .verify import (issue_to_finding, param_leaf_specs, verify_programs,
-                     verify_programs_by_key)
+from .verify import issue_to_finding, verify_programs, verify_programs_by_key
 
 __all__ = [
     "DEFAULT_CONST_THRESHOLD", "HOST_CALLBACK_PRIMS", "IRIssue",
@@ -40,6 +39,6 @@ __all__ = [
     "PallasCallCapture", "check_capture", "intercept_pallas_calls",
     "lint_pallas_kernels",
     "RetraceSentinel",
-    "issue_to_finding", "param_leaf_specs", "verify_programs",
+    "issue_to_finding", "verify_programs",
     "verify_programs_by_key",
 ]

@@ -15,9 +15,9 @@ infer from source text:
     ground truth: actually-aliased args carry a `tf.aliasing_output`
     attribute.
   * const bloat — closed-over arrays become jaxpr consts baked into the
-    executable.  An engine declares its model param leaves; any other
-    const above the threshold is closure-capture bloat (a table that
-    should have been an argument).
+    executable.  Serving programs take the model params and every table
+    as arguments, so any const above the threshold is closure-capture
+    bloat (an array that should have been an argument).
 
 Every check returns `IRIssue`s — (category, message, file, line) tuples
 the verify layer turns into registry Findings.  Issues carry the eqn's
@@ -27,7 +27,6 @@ python def-site, so inline suppressions keep working.
 from __future__ import annotations
 
 import re
-from collections import Counter
 from dataclasses import dataclass
 from typing import Iterator, List, Optional, Tuple
 
@@ -35,14 +34,14 @@ __all__ = ["IRIssue", "iter_eqns", "find_host_callbacks", "find_f64",
            "find_const_bloat", "count_aliased_inputs", "check_donation",
            "donation_report", "DEFAULT_CONST_THRESHOLD"]
 
-#: consts above this byte count that are not declared (model params) are
-#: flagged as closure-capture bloat; small baked scalars/tables are normal
+#: consts above this byte count are flagged as closure-capture bloat;
+#: small baked scalars/tables are normal
 DEFAULT_CONST_THRESHOLD = 1 << 16        # 64 KiB
 
 #: primitives whose presence in a serving program means a host round trip
 HOST_CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "infeed", "outfeed"})
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "infeed", "outfeed"})
 
 
 @dataclass(frozen=True)
@@ -170,29 +169,22 @@ def _nbytes(c) -> int:
     return int(size) * int(item)
 
 
-def find_const_bloat(closed_jaxpr, declared_specs=(),
+def find_const_bloat(closed_jaxpr,
                      threshold_bytes: int = DEFAULT_CONST_THRESHOLD
                      ) -> List[IRIssue]:
-    """Closed-over consts above `threshold_bytes` that are NOT in the
-    declared (shape, dtype-name) multiset — for an engine program the
-    declared set is its model param leaves, so a flagged const is some
-    other array baked into the executable instead of passed as an
+    """Closed-over consts above `threshold_bytes`: each is an array (model
+    params included) baked into the executable instead of passed as an
     argument."""
-    budget = Counter(tuple(s) if not isinstance(s, tuple) else s
-                     for s in declared_specs)
     issues = []
     for i, c in enumerate(closed_jaxpr.consts):
-        spec = _const_spec(c)
-        if budget[spec] > 0:
-            budget[spec] -= 1            # a declared (param) leaf
-            continue
         nb = _nbytes(c)
         if nb > threshold_bytes:
+            shape, dtype = _const_spec(c)
             issues.append(IRIssue(
                 "const-bloat",
-                f"undeclared closed-over const #{i}: shape {spec[0]} "
-                f"{spec[1]}, {nb} bytes (> {threshold_bytes}) baked into "
-                f"the executable — pass it as an argument instead"))
+                f"closed-over const #{i}: shape {shape} {dtype}, {nb} "
+                f"bytes (> {threshold_bytes}) baked into the executable — "
+                f"pass it as an argument instead"))
     return issues
 
 

@@ -11,8 +11,8 @@ demand via `engine._capture_program_ir()`; this module walks them:
   ir-donation        donate_argnums claims actually alias (engine
                      programs donate nothing today, so this validates
                      the claim-vs-alias bookkeeping stays consistent)
-  ir-const-bloat     consts == the declared model param leaves; any
-                     other const > threshold is closure-capture bloat
+  ir-const-bloat     no const > threshold: params and tables are
+                     program operands, a big const is closure capture
 
 Findings anchor on the eqn's user-frame source line when jax recorded
 one (so `# repro-lint: disable=ir-*` inline suppressions work), else on
@@ -21,14 +21,13 @@ the program's python def-site.
 from __future__ import annotations
 
 import os
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 from ..base import Finding
 from .jaxpr_checks import (DEFAULT_CONST_THRESHOLD, IRIssue, find_const_bloat,
                            find_f64, find_host_callbacks)
 
-__all__ = ["verify_programs", "verify_programs_by_key", "issue_to_finding",
-           "param_leaf_specs"]
+__all__ = ["verify_programs", "verify_programs_by_key", "issue_to_finding"]
 
 _CATEGORY_RULE = {
     "host-callback": "ir-host-callback",
@@ -81,15 +80,6 @@ def issue_to_finding(issue: IRIssue, root: str, *,
                    snippet=_read_line(root, rel, max(int(line), 1)))
 
 
-def param_leaf_specs(params) -> Tuple[Tuple[tuple, str], ...]:
-    """(shape, dtype-name) multiset of a param pytree's leaves — the
-    consts an engine program is *supposed* to close over."""
-    import jax
-    return tuple(
-        (tuple(getattr(leaf, "shape", ())), str(getattr(leaf, "dtype", "")))
-        for leaf in jax.tree_util.tree_leaves(params))
-
-
 def _engine_level_issues(engine) -> List[IRIssue]:
     """Checks on engine-owned host tables that feed the programs: the
     noise-schedule tables are gathered into every tick, so an f64 table
@@ -119,8 +109,7 @@ def verify_programs_by_key(engine, *, root: Optional[str] = None,
         issues = []
         issues += find_host_callbacks(ir.jaxpr)
         issues += find_f64(ir.jaxpr)
-        issues += find_const_bloat(ir.jaxpr, ir.declared_const_specs,
-                                   const_threshold)
+        issues += find_const_bloat(ir.jaxpr, const_threshold)
         # engine programs donate nothing today; an aliasing attr showing
         # up anyway would mean the jit wrappers grew donation the engine
         # does not account for — surface it rather than ignore it

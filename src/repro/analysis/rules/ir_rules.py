@@ -72,12 +72,12 @@ class IRDtypeRule(ProjectRule):
 @register
 class IRConstBloatRule(ProjectRule):
     id = "ir-const-bloat"
-    description = ("large closed-over constants baked into compiled "
-                   "programs beyond the declared model param leaves")
-    rationale = ("every undeclared baked const is duplicated per program "
-                 "variant (one per bucket size) and invalidates the "
-                 "executable when the host object changes — tables belong "
-                 "in arguments")
+    description = ("large closed-over constants (model params included) "
+                   "baked into compiled serving programs")
+    rationale = ("every baked const is duplicated per program variant "
+                 "(one per bucket size) and invalidates the executable "
+                 "when the host object changes — params and tables "
+                 "belong in arguments")
 
     def check_project(self, root: str) -> List[Finding]:
         return _program_findings(self.id)

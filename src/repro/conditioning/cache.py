@@ -70,7 +70,7 @@ class PromptCache:
         self.misses = 0
         self.evictions = 0
 
-        def _encode(ids, mask):
+        def _encode(params, ids, mask):
             # the batch squeeze lives INSIDE the program: an eager [0] on
             # the result would compile tiny slice/squeeze programs at the
             # first in-session miss, tripping the retrace sentinel
@@ -116,7 +116,8 @@ class PromptCache:
             self._count("hits")
             return hit
         fn = self._compiled if self._compiled is not None else self._encode
-        emb_dev, pool_dev = fn(jnp.asarray(ids[None]), jnp.asarray(mask[None]))
+        emb_dev, pool_dev = fn(self.params, jnp.asarray(ids[None]),
+                               jnp.asarray(mask[None]))
         # repro-lint: disable-next-line=host-sync-in-hot-path -- admission-
         # time transfer, paid once per UNIQUE prompt (never per tick/step)
         emb = np.asarray(emb_dev, np.float32)
@@ -131,37 +132,26 @@ class PromptCache:
         return entry
 
     # ------------------------------------------------------------------
-    def param_leaf_specs(self):
-        """(shape, dtype-name) multiset of the encoder's param leaves —
-        what the engine declares to the ir-const-bloat check so warmup
-        verification stays clean over the text-encoder program."""
-        return tuple((tuple(leaf.shape), leaf.dtype.name)
-                     for leaf in jax.tree_util.tree_leaves(self.params))
-
     def _example_args(self):
         L = self.tc.max_len
-        return (jnp.zeros((1, L), jnp.int32), jnp.zeros((1, L), bool))
+        return (self.params, jnp.zeros((1, L), jnp.int32),
+                jnp.zeros((1, L), bool))
 
-    def warmup(self, verify: bool = False, declared_const_specs=None):
+    def warmup(self, verify: bool = False):
         """AOT-compile the encoder program; returns its ProgramProfile
         (plus the ProgramIR under `verify=True`).  The compiled executable
         replaces the lazy jit so post-warmup misses never trigger a
         compile."""
-        specs = (self.param_leaf_specs() if declared_const_specs is None
-                 else declared_const_specs)
         out = compile_program(self._encode, *self._example_args(),
-                              key="text_encoder", want_ir=verify,
-                              declared_const_specs=specs)
+                              key="text_encoder", want_ir=verify)
         self._compiled = out[0]
         return out[1:] if verify else out[1]
 
-    def capture_ir(self, declared_const_specs=None):
+    def capture_ir(self):
         """Re-capture the encoder program's IR (engine._capture_program_ir
         hook — a Compiled executable no longer carries its jaxpr)."""
-        specs = (self.param_leaf_specs() if declared_const_specs is None
-                 else declared_const_specs)
         return capture_ir(jax.jit(self._encode_src), *self._example_args(),
-                          key="text_encoder", declared_const_specs=specs)
+                          key="text_encoder")
 
     # ------------------------------------------------------------------
     @property

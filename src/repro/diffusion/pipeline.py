@@ -58,26 +58,30 @@ def backbone_module(cfg):
     return video_dit if cfg.dit_num_frames > 0 else dit
 
 
-def backbone_fns(params, cfg):
-    """(forward_fn, signal_fn) bound to params for this config's modality.
+def backbone_fns(cfg):
+    """(forward_fn, signal_fn) for this config's modality; both take the
+    model params as their first argument, so a jitted caller passes them as
+    program operands instead of baking them into the executable.
 
-    forward_fn(xs, ts, labels, y_embed=None, txt_kv=None, txt_mask=None)
-    -> eps — xs (B, T, D), ts (B,) float timesteps, labels (B,) int32 class
-    conditioning, y_embed (B, d) optional conditioning-vector override
-    (negative prompts), txt_kv/txt_mask the precomputed per-layer text K/V
-    tables + key mask (text-enabled configs; see models.dit.text_kv).
-    signal_fn(xs, ts, labels) -> the TeaCache modulated input signal
-    (computed BEFORE the first block, so it is text-independent by
+    forward_fn(params, xs, ts, labels, y_embed=None, txt_kv=None,
+    txt_mask=None) -> eps — xs (B, T, D), ts (B,) float timesteps, labels
+    (B,) int32 class conditioning, y_embed (B, d) optional conditioning-
+    vector override (negative prompts), txt_kv/txt_mask the precomputed
+    per-layer text K/V tables + key mask (text-enabled configs; see
+    models.dit.text_kv).
+    signal_fn(params, xs, ts, labels) -> the TeaCache modulated input
+    signal (computed BEFORE the first block, so it is text-independent by
     construction — prompts never perturb the refresh decision).
     """
     mod = backbone_module(cfg)
 
-    def forward_fn(xs, ts, labels, y_embed=None, txt_kv=None, txt_mask=None):
+    def forward_fn(params, xs, ts, labels, y_embed=None, txt_kv=None,
+                   txt_mask=None):
         return mod.forward(params, xs, ts.astype(jnp.float32),
                            labels.astype(jnp.int32), cfg, y_embed=y_embed,
                            txt_kv=txt_kv, txt_mask=txt_mask)
 
-    def signal_fn(xs, ts, labels):
+    def signal_fn(params, xs, ts, labels):
         h, c = mod.embed_patches(params, xs, ts.astype(jnp.float32),
                                  labels.astype(jnp.int32), cfg)
         return mod.modulated_signal(params, h, c, cfg)
@@ -312,72 +316,65 @@ class CachedDenoiser:
         return eps_c, new_state
 
 
-def slot_denoise_fns(params, cfg, policy: CachePolicy):
+def slot_denoise_fns(cfg, policy: CachePolicy):
     """Slot-parallel CachedDenoiser entry point (model granularity).
 
     The serving engine (repro.serving.diffusion) advances many concurrent
     requests, each at its own denoising step with its own cache state,
     through one compiled program.  The split that makes this fast:
 
-      backbone_fn(xs, ts, labels) -> eps        plain SLOT-BATCHED forward —
-          the slot axis IS the model's batch axis, so XLA sees the same
-          program as uncached batched inference.  (Running the backbone
-          inside vmap instead would thread a singleton batch dim through
-          every matmul, which knocks XLA CPU off its fast paths.)
-      apply_fn(state, step, x, t, label, y_full) -> (eps, state)   per-slot
-          policy logic, vmapped by the engine.  `y_full` is this slot's row
-          of backbone_fn's output; the compute branch selects it into the
-          cache, other branches reuse/forecast.  Every repro.core policy
-          calls compute_fn on exactly its input x, so precomputing F(x)
-          outside the branch is semantics-preserving.  On skip ticks the
-          engine passes zeros for y_full — ONLY safe when the policy's
-          want_compute is False for every slot (lax.cond vmaps to a select,
-          so the dummy branch's outputs are discarded).
-      want_fn(state, step, x, t, label) -> bool   mirrors the policy's
-          refresh decision without touching the backbone.
+      backbone_fn(params, xs, ts, labels) -> eps   plain SLOT-BATCHED
+          forward — the slot axis IS the model's batch axis, so XLA sees the
+          same program as uncached batched inference.  (Running the
+          backbone inside vmap instead would thread a singleton batch dim
+          through every matmul, which knocks XLA CPU off its fast paths.)
+      apply_fn(params, state, step, x, t, label, y_full) -> (eps, state)
+          per-slot policy logic, vmapped by the engine over everything but
+          `params`.  `y_full` is this slot's row of backbone_fn's output;
+          the compute branch selects it into the cache, other branches
+          reuse/forecast.  Every repro.core policy calls compute_fn on
+          exactly its input x, so precomputing F(x) outside the branch is
+          semantics-preserving.  On skip ticks the engine passes zeros for
+          y_full — ONLY safe when the policy's want_compute is False for
+          every slot (lax.cond vmaps to a select, so the dummy branch's
+          outputs are discarded; slot_want_fns is the traced mirror of
+          that decision).
 
-    x: (T, in_dim) latent tokens; t: scalar model-facing timestep; label:
-    scalar int32 class conditioning.  The backbone is the config's modality
-    backbone (image/audio DiT or factorized video DiT); TeaCache's
-    input-side signal (the AdaLN-modulated first-block input, Eq. 22) is
-    wired through when the policy declares `uses_signal`.
+    Every function takes the model params as its first argument (program
+    operands, never closed-over constants).  x: (T, in_dim) latent tokens;
+    t: scalar model-facing timestep; label: scalar int32 class
+    conditioning.  The backbone is the config's modality backbone
+    (image/audio DiT or factorized video DiT); TeaCache's input-side signal
+    (the AdaLN-modulated first-block input, Eq. 22) is wired through when
+    the policy declares `uses_signal`.
     """
-    forward_fn, signal_fn = backbone_fns(params, cfg)
+    forward_fn, signal_fn = backbone_fns(cfg)
 
-    def backbone_fn(xs, ts, labels, txt=None):
+    def backbone_fn(params, xs, ts, labels, txt=None):
         """txt: the engine's per-slot text-table dict ({} / None = no text;
         an EMPTY dict contributes zero jit operand leaves, so text-free
         engines keep the exact pre-text program signature).  Cond rows
         attend over k/v/mask — K/V were projected once at admission."""
         if not txt:
-            return forward_fn(xs, ts, labels)
-        return forward_fn(xs, ts, labels, txt_kv=(txt["k"], txt["v"]),
-                          txt_mask=txt["mask"])
+            return forward_fn(params, xs, ts, labels)
+        return forward_fn(params, xs, ts, labels,
+                          txt_kv=(txt["k"], txt["v"]), txt_mask=txt["mask"])
 
-    def _ctx(x, t, label):
+    def apply_fn(params, state, step, x, t, label, y_full):
         xb = x[None]
-        t_vec = jnp.reshape(t, (1,)).astype(jnp.float32)
-        y = jnp.reshape(label, (1,)).astype(jnp.int32)
-        if not policy.uses_signal:       # skip-tick cost: don't embed
-            return xb, {}
-        return xb, {"signal": signal_fn(xb, t_vec, y)}
-
-    def apply_fn(state, step, x, t, label, y_full):
-        xb, sig = _ctx(x, t, label)
+        sig = {}
+        if policy.uses_signal:           # skip-tick cost: don't embed
+            t_vec = jnp.reshape(t, (1,)).astype(jnp.float32)
+            y = jnp.reshape(label, (1,)).astype(jnp.int32)
+            sig = {"signal": signal_fn(params, xb, t_vec, y)}
         eps, state = policy.apply(state, step, xb, lambda _: y_full[None],
                                   **sig)
         return eps[0], state
 
-    def want_fn(state, step, x, t, label):
-        xb, sig = _ctx(x, t, label)
-        w = policy.want_compute(state, step, xb, **sig)
-        # `& step >= 0` keeps constant predicates mapped under vmap
-        return jnp.logical_and(jnp.asarray(w), step >= 0)
-
-    return backbone_fn, apply_fn, want_fn
+    return backbone_fn, apply_fn
 
 
-def slot_cfg_denoise_fns(params, cfg, policy: CachePolicy,
+def slot_cfg_denoise_fns(cfg, policy: CachePolicy,
                          cfg_policy: Optional[CachePolicy] = None):
     """CFG-aware slot-parallel entry point for the serving engine.
 
@@ -387,18 +384,19 @@ def slot_cfg_denoise_fns(params, cfg, policy: CachePolicy,
     branch recomputes every step — naive two-branch serving).  The backbone
     still runs OUTSIDE vmap; on both-branch ticks the engine stacks cond and
     uncond rows into one 2S-row batch (slot axis == batch axis), so XLA sees
-    a plain batched forward either way.
+    a plain batched forward either way.  Every function takes the model
+    params first.
 
-      backbone2_fn(xs, ts, labels, null_labels, null_vecs, null_mask)
+      backbone2_fn(params, xs, ts, labels, null_labels, null_vecs, null_mask)
           one 2S-row backbone pass over [cond rows; uncond rows], split back
           into the two S-row branch outputs.  `null_vecs` (S, d_model) with
           `null_mask` (S,) carry per-slot negative-prompt conditioning
           vectors that replace the null-class embedding on uncond rows.
-      backbone_fn(xs, ts, labels) -> eps_c
+      backbone_fn(params, xs, ts, labels) -> eps_c
           the S-row cond-only pass (from slot_denoise_fns), dispatched on
           ticks where every active slot reuses its cached uncond branch —
           this is where FasterCacheCFG's serving-level saving comes from.
-      apply_fn(state, step, x, t, label, scale, cfg_w, y_c, y_u)
+      apply_fn(params, state, step, x, t, label, scale, cfg_w, y_c, y_u)
           per-slot (vmapped) policy logic over the combined state
           {"policy": ..., "cfg": ...}.  `scale` is the slot's cfg_scale
           (<= 0 means unguided: the uncond branch output is discarded via a
@@ -411,17 +409,13 @@ def slot_cfg_denoise_fns(params, cfg, policy: CachePolicy,
           y_u / y_c rows — safe under the same rule as slot_denoise_fns:
           a dummy row may only reach a branch that the per-slot lax.cond
           (vmapped to a select) discards.
-      want_cond_fn / want_uncond_fn
-          traced mirrors of the two refresh decisions; `want_uncond_fn`
-          additionally masks by the slot's `guided` flag so pure-unguided
-          pools never dispatch the 2S-row program.
     """
     uncond_policy = cfg_policy if cfg_policy is not None else NoCachePolicy()
-    forward_fn, _ = backbone_fns(params, cfg)
-    backbone_fn, base_apply, base_want = slot_denoise_fns(params, cfg, policy)
+    forward_fn, _ = backbone_fns(cfg)
+    backbone_fn, base_apply = slot_denoise_fns(cfg, policy)
 
-    def backbone2_fn(xs, ts, labels, null_labels, null_vecs, null_mask,
-                     txt=None):
+    def backbone2_fn(params, xs, ts, labels, null_labels, null_vecs,
+                     null_mask, txt=None):
         S = xs.shape[0]
         x2 = jnp.concatenate([xs, xs], axis=0)
         t2 = jnp.concatenate([ts, ts], axis=0).astype(jnp.float32)
@@ -436,12 +430,13 @@ def slot_cfg_denoise_fns(params, cfg, policy: CachePolicy,
                              jnp.concatenate([txt["v"], txt["nv"]], axis=0)),
                   "txt_mask": jnp.concatenate([txt["mask"], txt["nmask"]],
                                               axis=0)}
-        eps = forward_fn(x2, t2, y2,
+        eps = forward_fn(params, x2, t2, y2,
                          y_embed=jnp.concatenate([ce_c, ce_u], axis=0), **kw)
         return eps[:S], eps[S:]
 
-    def apply_fn(state, step, x, t, label, scale, cfg_w, y_c, y_u):
-        eps_c, pol_state = base_apply(state["policy"], step, x, t, label, y_c)
+    def apply_fn(params, state, step, x, t, label, scale, cfg_w, y_c, y_u):
+        eps_c, pol_state = base_apply(params, state["policy"], step, x, t,
+                                      label, y_c)
         eps_u, cfg_state = uncond_policy.apply(state["cfg"], step, x[None],
                                                lambda _: y_u[None],
                                                cfg_w=cfg_w,
@@ -450,19 +445,10 @@ def slot_cfg_denoise_fns(params, cfg, policy: CachePolicy,
         eps = jnp.where(scale > 0.0, eps_u + scale * (eps_c - eps_u), eps_c)
         return eps, {"policy": pol_state, "cfg": cfg_state}
 
-    def want_cond_fn(state, step, x, t, label):
-        return base_want(state["policy"], step, x, t, label)
-
-    def want_uncond_fn(state, step, x, guided):
-        w = uncond_policy.want_compute(state["cfg"], step, x[None])
-        w = jnp.logical_and(jnp.asarray(w), guided)
-        # `& step >= 0` keeps constant predicates mapped under vmap
-        return jnp.logical_and(w, step >= 0)
-
-    return backbone2_fn, backbone_fn, apply_fn, want_cond_fn, want_uncond_fn
+    return backbone2_fn, backbone_fn, apply_fn
 
 
-def slot_compact_denoise_fns(params, cfg, policy: CachePolicy,
+def slot_compact_denoise_fns(cfg, policy: CachePolicy,
                              cfg_policy: Optional[CachePolicy] = None):
     """Row-compacted slot-parallel entry point for the serving engine.
 
@@ -474,8 +460,9 @@ def slot_compact_denoise_fns(params, cfg, policy: CachePolicy,
     policies want a compute this tick, padded to a power-of-two bucket so the
     jit program count stays bounded (one program per bucket size):
 
-      compact_backbone_fn(xs, tvals, labels, nulls, null_vecs, null_mask,
-                          row_slot, row_uncond, row_dest) -> (y_c, y_u)
+      compact_backbone_fn(params, xs, tvals, labels, nulls, null_vecs,
+                          null_mask, txt, row_slot, row_uncond, row_dest)
+                          -> (y_c, y_u)
           `row_slot` (B,) gathers each compacted row's latent/timestep from
           its source slot; `row_uncond` selects the null label (or the
           slot's negative-prompt vector, where `null_mask` is set) for
@@ -488,7 +475,7 @@ def slot_compact_denoise_fns(params, cfg, policy: CachePolicy,
           only reach a branch the per-slot lax.cond (vmapped to a select)
           discards, i.e. the gather set must cover every row whose policy
           `want_compute` is True.
-      apply_fn / want_cond_fn / want_uncond_fn
+      backbone2_fn / backbone_fn / apply_fn
           unchanged from `slot_cfg_denoise_fns` — compaction only changes
           how y_c / y_u are produced, never the per-slot policy step.
 
@@ -496,12 +483,12 @@ def slot_compact_denoise_fns(params, cfg, policy: CachePolicy,
     B serves every gather pattern of that size.  B is static per program:
     the engine re-pads each tick's row set to the next power of two.
     """
-    forward_fn, _ = backbone_fns(params, cfg)
-    (backbone2_fn, backbone_fn, apply_fn, want_cond_fn,
-     want_uncond_fn) = slot_cfg_denoise_fns(params, cfg, policy, cfg_policy)
+    forward_fn, _ = backbone_fns(cfg)
+    backbone2_fn, backbone_fn, apply_fn = slot_cfg_denoise_fns(
+        cfg, policy, cfg_policy)
 
-    def compact_backbone_fn(xs, tvals, labels, nulls, null_vecs, null_mask,
-                            txt, row_slot, row_uncond, row_dest):
+    def compact_backbone_fn(params, xs, tvals, labels, nulls, null_vecs,
+                            null_mask, txt, row_slot, row_uncond, row_dest):
         S, T, D = xs.shape
         xb = xs[row_slot]
         tb = tvals[row_slot].astype(jnp.float32)
@@ -523,40 +510,40 @@ def slot_compact_denoise_fns(params, cfg, policy: CachePolicy,
                   "txt_mask": jnp.where(row_uncond[:, None],
                                         txt["nmask"][row_slot],
                                         txt["mask"][row_slot])}
-        eps = forward_fn(xb, tb, yb, y_embed=ce, **kw)
+        eps = forward_fn(params, xb, tb, yb, y_embed=ce, **kw)
         # scatter: padding rows all land in the 2S dump row and are dropped
         buf = jnp.zeros((2 * S + 1, T, D), eps.dtype).at[row_dest].set(eps)
         return buf[:S], buf[S:2 * S]
 
-    return (compact_backbone_fn, backbone2_fn, backbone_fn, apply_fn,
-            want_cond_fn, want_uncond_fn)
+    return compact_backbone_fn, backbone2_fn, backbone_fn, apply_fn
 
 
-def slot_want_fns(params, cfg, policy: CachePolicy,
+def slot_want_fns(cfg, policy: CachePolicy,
                   cfg_policy: Optional[CachePolicy] = None):
     """Fused slot-batched want/metric pass for the serving engine's planner.
 
-    The per-slot want predicates of `slot_cfg_denoise_fns` compute a
-    signal-using policy's TeaCache signal on a SINGLETON batch inside vmap —
-    the modulated-embed matmuls thread a batch-1 dim through XLA, and the
-    engine paid two separate device syncs per tick (cond plan, then uncond
-    plan).  This entry point fuses the whole plan into one program:
+    Computing a signal-using policy's TeaCache signal per slot on a
+    SINGLETON batch inside vmap would thread a batch-1 dim through the
+    modulated-embed matmuls, and planning the cond and uncond branches
+    separately would cost two device syncs per tick.  This entry point
+    fuses the whole plan into one program:
 
-      want_all_fn(states, steps, xs, tvals, labels, guided)
+      want_all_fn(params, states, steps, xs, tvals, labels, guided)
           -> (want_cond, want_uncond, metric)     each (S,)
 
     The TeaCache signal is computed ONCE over the whole (S, T, D) slot batch
     outside vmap (slot axis == batch axis, same layout as the backbone
     call), then handed row-wise to the vmapped per-slot predicates.  The
     batched embed is row-independent, so each slot sees exactly the signal
-    the singleton path produced.  `metric` is the per-slot
-    `CachePolicy.want_metric` scalar (the value the refresh decision
-    thresholds on — TeaCache's corrected accumulated distance, the LazyDiT
-    gate score, 0 for schedule-only policies), which the control plane's
-    SignalTraceLog records; it rides the same device round trip, so trace
-    logging costs no extra sync."""
+    the singleton path produced.  `want_uncond` is masked by the slot's
+    `guided` flag, so pure-unguided pools never dispatch uncond rows.
+    `metric` is the per-slot `CachePolicy.want_metric` scalar (the value
+    the refresh decision thresholds on — TeaCache's corrected accumulated
+    distance, the LazyDiT gate score, 0 for schedule-only policies), which
+    the control plane's SignalTraceLog records; it rides the same device
+    round trip, so trace logging costs no extra sync."""
     uncond_policy = cfg_policy if cfg_policy is not None else NoCachePolicy()
-    _, signal_fn = backbone_fns(params, cfg)
+    _, signal_fn = backbone_fns(cfg)
 
     def per_slot(state, step, x, sig, g):
         xb = x[None]
@@ -571,9 +558,9 @@ def slot_want_fns(params, cfg, policy: CachePolicy,
         wu = jnp.logical_and(jnp.logical_and(jnp.asarray(wu), g), step >= 0)
         return wc, wu, m + 0.0 * step.astype(jnp.float32)
 
-    def want_all_fn(states, steps, xs, tvals, labels, guided):
+    def want_all_fn(params, states, steps, xs, tvals, labels, guided):
         if policy.uses_signal:
-            sigs = signal_fn(xs, tvals.astype(jnp.float32),
+            sigs = signal_fn(params, xs, tvals.astype(jnp.float32),
                              labels.astype(jnp.int32))
         else:                            # dummy rows: per_slot never reads them
             sigs = jnp.zeros((xs.shape[0], 1, 1), jnp.float32)
@@ -591,8 +578,11 @@ def cfg_denoise_fn(params, cfg, cfg_scale: float, class_label: int = 0,
     (PromptEmbedding or (embed, mask); text-enabled configs) condition the
     cond / uncond branch through cross-attention; K/V are projected once at
     construction, and a neg_text prompt defaults `null_embed` to its pooled
-    embedding — the same convention CachedDenoiser and the engine use."""
-    forward_fn, _ = backbone_fns(params, cfg)
+    embedding — the same convention CachedDenoiser and the engine use.
+
+    The backbone call is jitted with the params as an operand: run eagerly,
+    its layer scan would re-trace and re-compile on every step."""
+    forward_fn = jax.jit(backbone_fns(cfg)[0])
     txt = _as_text(text, cfg)
     neg = _as_text(neg_text, cfg)
     txt_kv = None if txt is None else dit.text_kv(params, txt[0][None], cfg)
@@ -614,11 +604,12 @@ def cfg_denoise_fn(params, cfg, cfg_scale: float, class_label: int = 0,
         B = x.shape[0]
         y_c = jnp.full((B,), class_label, jnp.int32)
         y_u = jnp.full((B,), cfg.dit_num_classes, jnp.int32)
-        e_c = forward_fn(x, t_vec, y_c, **_kw(txt_kv, txt, B))
+        e_c = forward_fn(params, x, t_vec, y_c, **_kw(txt_kv, txt, B))
         if cfg_scale <= 0.0:
             return e_c, state
         ye = None if ne is None else jnp.broadcast_to(ne[None],
                                                       (B, cfg.d_model))
-        e_u = forward_fn(x, t_vec, y_u, y_embed=ye, **_kw(neg_kv, neg, B))
+        e_u = forward_fn(params, x, t_vec, y_u, y_embed=ye,
+                         **_kw(neg_kv, neg, B))
         return e_u + cfg_scale * (e_c - e_u), state
     return fn

@@ -7,15 +7,13 @@
 
 Each module ships `<name>.py` (pl.pallas_call + BlockSpec), `ops.py` (jit'd
 public wrapper choosing kernel vs reference) and `ref.py` (pure-jnp oracle).
-This container is CPU-only: kernels run under interpret=True in tests; on a
-real TPU set REPRO_PALLAS_INTERPRET=0.
+The wrappers run the Pallas interpreter when the default backend is the CPU
+and the compiled Mosaic kernel otherwise; an explicit `interpret=` argument
+overrides that.  tests/test_tpu_compile.py compiles every kernel for a
+described TPU v5e, which interpret mode cannot check.
 """
-import os
+from .flash_attention.ops import flash_attention
+from .forecast.ops import forecast
+from .ssd.ops import ssd_scan
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
-
-from .flash_attention.ops import flash_attention          # noqa: E402
-from .forecast.ops import forecast                        # noqa: E402
-from .ssd.ops import ssd_scan                             # noqa: E402
-
-__all__ = ["flash_attention", "forecast", "ssd_scan", "INTERPRET"]
+__all__ = ["flash_attention", "forecast", "ssd_scan"]

@@ -4,7 +4,7 @@ TPU-native design (not a CUDA port):
   * grid = (B, KH, Sq/BQ); each program owns one (128-ish, D) Q tile for one
     KV head group, resident in VMEM.
   * K/V are streamed through VMEM in (BK, D) tiles by an inner fori_loop
-    over `pl.load` slices of the full-(Sk) VMEM block — HBM->VMEM movement
+    over `pl.ds` ref slices of the full-(Sk) VMEM block — HBM->VMEM movement
     is expressed by the BlockSpec, tile iteration stays on-chip.
   * online softmax: running (m, l, acc) in f32 VREGs; one store per Q tile.
   * GQA: the `group` dimension is folded into the Q-tile rows (BQ rows hold
@@ -50,8 +50,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, BQ: int, BK: int, Sk: int,
 
     def body(ki, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.ds(ki * BK, BK), slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.ds(ki * BK, BK), slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(ki * BK, BK), :].astype(jnp.float32)
+        v = v_ref[pl.ds(ki * BK, BK), :].astype(jnp.float32)
         s = q2 @ k.T                                    # (BQ*G, BK)
         k_pos = ki * BK + jax.lax.broadcasted_iota(jnp.int32, (1, BK), 1)
         ok = jnp.ones_like(s, dtype=jnp.bool_)

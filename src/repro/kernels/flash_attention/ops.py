@@ -14,9 +14,8 @@ from .ref import attention_ref
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
                     block_k=128, interpret=None, use_kernel=True):
     """Drop-in attention: Pallas kernel on TPU, interpret-mode on CPU."""
-    if interpret is None:
-        from repro.kernels import INTERPRET
-        interpret = INTERPRET
+    if interpret is None:                 # decided when the call is traced
+        interpret = jax.default_backend() == "cpu"
     if not use_kernel:
         return attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,

@@ -28,6 +28,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr,
                 *, nc: int):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -35,32 +36,35 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = x_ref[...].astype(jnp.float32)        # (L, P)
-    dt = dt_ref[...].astype(jnp.float32)      # (L,)
-    A = a_ref[0].astype(jnp.float32)          # scalar (negative)
+    dt = dt_ref[pl.ds(ci, 1), :].astype(jnp.float32)   # (1, L) this chunk
+    A = a_ref[hi].astype(jnp.float32)         # scalar (negative), from SMEM
     B = b_ref[...].astype(jnp.float32)        # (L, N)
     C = c_ref[...].astype(jnp.float32)        # (L, N)
     L = x.shape[0]
 
-    dA = dt * A                               # (L,) <= 0
-    cs = jnp.cumsum(dA)                       # inclusive
-    # segsum decay matrix: exp(cs_i - cs_j + dA_j) for j <= i  ... note the
-    # convention: contribution of token j to token i decays by
-    # exp(sum_{k=j+1..i} dA_k) = exp(cs_i - cs_j)
-    seg = cs[:, None] - cs[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    Lmat = jnp.where(jj <= ii, jnp.exp(seg), 0.0)
+    causal = jj <= ii
+    diag = ii == jj
+    dA = dt * A                               # (1, L) <= 0
+    # inclusive cumsum as a masked lane reduction (cs_i = sum_{k<=i} dA_k);
+    # the diagonal picks move the column vectors back into row layout
+    cs_col = jnp.sum(jnp.where(causal, dA, 0.0), axis=1, keepdims=True)
+    cs_row = jnp.sum(jnp.where(diag, cs_col, 0.0), axis=0, keepdims=True)
+    dt_col = jnp.sum(jnp.where(diag, dt, 0.0), axis=1, keepdims=True)
+    # segsum decay: contribution of token j to token i decays by
+    # exp(sum_{k=j+1..i} dA_k) = exp(cs_i - cs_j), j <= i
+    Lmat = jnp.where(causal, jnp.exp(cs_col - cs_row), 0.0)
 
     scores = (C @ B.T) * Lmat                 # (L, L)
-    xdt = x * dt[:, None]                     # (L, P)
-    y = scores @ xdt                          # intra-chunk
+    y = scores @ (x * dt_col)                 # intra-chunk (L, P)
 
     h_prev = h_scr[...]                       # (P, N)
-    y = y + (C * jnp.exp(cs)[:, None]) @ h_prev.T
+    y = y + (C * jnp.exp(cs_col)) @ h_prev.T
 
-    chunk_decay = jnp.exp(cs[-1])
-    decay_dt = jnp.exp(cs[-1] - cs) * dt      # (L,)
-    h_new = h_prev * chunk_decay + (x * decay_dt[:, None]).T @ B
+    total = jnp.sum(dA, axis=1, keepdims=True)        # (1, 1) = cs[-1]
+    decay_dt = jnp.exp(total - cs_col) * dt_col       # (L, 1)
+    h_new = h_prev * jnp.exp(total) + (x * decay_dt).T @ B
     h_scr[...] = h_new
 
     y_ref[...] = y.astype(y_ref.dtype)
@@ -81,7 +85,9 @@ def ssd_pallas(x, dt, A, B_, C_, *, chunk: int = 64, interpret: bool = True):
     nc = s // chunk
 
     xt = x.transpose(0, 2, 1, 3)              # (b,h,s,p)
-    dtt = dt.transpose(0, 2, 1)               # (b,h,s)
+    # (b,h,nc,chunk): each (b,h) program column holds all of its dt rows
+    # in one tile-aligned block and slices its chunk's row in-kernel
+    dtt = dt.transpose(0, 2, 1).reshape(b, h, nc, chunk)
 
     grid = (b, h, nc)
     y, h_fin = pl.pallas_call(
@@ -89,8 +95,9 @@ def ssd_pallas(x, dt, A, B_, C_, *, chunk: int = 64, interpret: bool = True):
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, None, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((None, None, chunk), lambda bi, hi, ci: (bi, hi, ci)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            pl.BlockSpec((None, None, nc, chunk),
+                         lambda bi, hi, ci: (bi, hi, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),       # A: (h,) scalars
             pl.BlockSpec((None, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((None, chunk, n), lambda bi, hi, ci: (bi, ci, 0)),
         ],

@@ -134,8 +134,7 @@ def _ep_body(x, router, w_gate, w_up, w_down, *, cfg, ep_axis, inner_axes,
     w_*: (E_loc, d, ff_loc) local expert shards.  Returns (y_loc, lb, z)."""
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.experts_per_token
-    ep = (jax.lax.axis_size(ep_axis) if hasattr(jax.lax, "axis_size")
-          else jax.lax.psum(1, ep_axis))
+    ep = jax.lax.axis_size(ep_axis)
     T = B * S
     xt = x.reshape(T, d)
 
@@ -206,16 +205,11 @@ def moe_forward_ep(p, x, cfg, *, mesh, batch_ax=("data",), ep_axis="data",
     down_spec = P(ep_axis, inner_axes or None, None)
     x_spec = P(batch_ax, None, None)
 
-    if hasattr(jax, "shard_map"):               # jax >= 0.6
-        smap, relax = jax.shard_map, {"check_vma": False}
-    else:                                       # jax 0.4.x
-        from jax.experimental.shard_map import shard_map as smap
-        relax = {"check_rep": False}
-    y, lb, z = smap(
+    y, lb, z = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), ff_spec, ff_spec, down_spec),
         out_specs=(x_spec, P(), P()),
-        **relax,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if "shared" in p:

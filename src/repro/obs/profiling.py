@@ -57,20 +57,17 @@ class ProgramIR:
     captured at trace/lower time (a `Compiled` executable no longer
     carries its jaxpr, so engines capture this during warmup).
 
-    `jaxpr` is the ClosedJaxpr — closed-over arrays (model params, any
-    accidentally baked table) appear as `.consts`.  `lowered_text` is the
-    StableHLO module as text; donated-and-actually-aliased arguments carry
-    a `tf.aliasing_output` attribute there, which is what the ir-donation
-    check keys on.  `declared_const_specs` is the (shape, dtype-name)
-    multiset of consts the owner *intends* to close over (an engine's
-    model param leaves); anything else above the bloat threshold is a
-    closure-capture leak."""
+    `jaxpr` is the ClosedJaxpr — closed-over arrays (an accidentally baked
+    table or param tree) appear as `.consts`; the model params are program
+    operands, so a large const is always a closure-capture leak.
+    `lowered_text` is the StableHLO module as text; donated-and-actually-
+    aliased arguments carry a `tf.aliasing_output` attribute there, which
+    is what the ir-donation check keys on."""
     key: object
     jaxpr: object                              # jax ClosedJaxpr
     lowered_text: str                          # StableHLO module text
     fn_file: str = ""                          # def-site of the python fn
     fn_line: int = 0
-    declared_const_specs: Tuple = ()           # ((shape, dtype_name), ...)
 
 
 def _fn_def_site(jitted) -> Tuple[str, int]:
@@ -83,8 +80,7 @@ def _fn_def_site(jitted) -> Tuple[str, int]:
     return code.co_filename, code.co_firstlineno
 
 
-def capture_ir(jitted, *args, key=None, declared_const_specs=(),
-               **kwargs) -> ProgramIR:
+def capture_ir(jitted, *args, key=None, **kwargs) -> ProgramIR:
     """Trace + lower a jit'd function on example args and keep the IRs
     (without compiling).  Engines use this to re-capture IR for programs
     whose compiled executables were already swapped in by a prior warmup."""
@@ -92,8 +88,7 @@ def capture_ir(jitted, *args, key=None, declared_const_specs=(),
     fn_file, fn_line = _fn_def_site(jitted)
     return ProgramIR(key=key, jaxpr=traced.jaxpr,
                      lowered_text=traced.lower().as_text(),
-                     fn_file=fn_file, fn_line=fn_line,
-                     declared_const_specs=tuple(declared_const_specs))
+                     fn_file=fn_file, fn_line=fn_line)
 
 
 def program_cost(compiled) -> Dict[str, float]:
@@ -115,8 +110,7 @@ def program_cost(compiled) -> Dict[str, float]:
             "bytes_accessed": float(ca.get("bytes accessed", math.nan))}
 
 
-def compile_program(jitted, *args, key=None, want_ir=False,
-                    declared_const_specs=(), **kwargs):
+def compile_program(jitted, *args, key=None, want_ir=False, **kwargs):
     """AOT-compile a jit'd function on example args.
 
     Returns (compiled, ProgramProfile) — or (compiled, profile, ProgramIR)
@@ -133,8 +127,7 @@ def compile_program(jitted, *args, key=None, want_ir=False,
         fn_file, fn_line = _fn_def_site(jitted)
         ir = ProgramIR(key=key, jaxpr=traced.jaxpr,
                        lowered_text=lowered.as_text(),
-                       fn_file=fn_file, fn_line=fn_line,
-                       declared_const_specs=tuple(declared_const_specs))
+                       fn_file=fn_file, fn_line=fn_line)
     else:
         lowered = jitted.lower(*args, **kwargs)
     compiled = lowered.compile()

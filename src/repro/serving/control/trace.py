@@ -141,7 +141,7 @@ def probe_training_set(params, cfg, trace: SignalTraceLog,
     same layout trick the serving engine uses for slots) and returns
     [(inputs (T, tokens, D), exact outputs (T, tokens, D)), ...].  Probes
     shorter than `min_steps` carry no skippable structure and are dropped."""
-    forward_fn, _ = backbone_fns(params, cfg)
+    forward_fn, _ = backbone_fns(cfg)
     sets = []
     for rid in sorted(trace.probes):
         p = trace.probes[rid]
@@ -150,7 +150,7 @@ def probe_training_set(params, cfg, trace: SignalTraceLog,
         xs = jnp.asarray(np.stack(p["xs"]))
         tv = jnp.asarray(np.asarray(p["tvals"], np.float32))
         labels = jnp.full((xs.shape[0],), p["label"], jnp.int32)
-        eps = forward_fn(xs, tv, labels)
+        eps = forward_fn(params, xs, tv, labels)
         sets.append((xs, eps))
     return sets
 

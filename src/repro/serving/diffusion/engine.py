@@ -64,6 +64,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -367,8 +368,9 @@ class ServeSession:
         # engine's actual batch; also what row compaction saves against)
         dense_rows = {"full": 2 * eng.slots, "cond": eng.slots,
                       "skip": 0}[kind]
-        args = (self.states, jnp.asarray(idx), self.xs, jnp.asarray(tvals),
-                jnp.asarray(eng._labels), jnp.asarray(eng._nulls),
+        args = (eng.params, self.states, jnp.asarray(idx), self.xs,
+                jnp.asarray(tvals), jnp.asarray(eng._labels),
+                jnp.asarray(eng._nulls),
                 self._null_vecs, self._null_mask, self._txt,
                 jnp.asarray(eng._scales), jnp.asarray(cfg_ws),
                 jnp.asarray(ab_t), jnp.asarray(ab_n))
@@ -555,9 +557,10 @@ class DiffusionServingEngine:
         self._feat = (1, T, D)                      # per-slot policy feature
         self._sig_shape = (1, T, cfg.d_model)       # TeaCache signal shape
         self.batched = SlotBatchedPolicy(self.policy, slots)
-        (compact_backbone_fn, backbone2_fn, backbone_fn, apply_fn,
-         want_cond_fn, want_uncond_fn) = slot_compact_denoise_fns(
-            params, cfg, self.policy, cfg_policy)
+        # every program below takes the model params as its first operand:
+        # closed over, jit would bake them into each executable as literals
+        (compact_backbone_fn, backbone2_fn, backbone_fn,
+         apply_fn) = slot_compact_denoise_fns(cfg, self.policy, cfg_policy)
         # combined per-slot state: main policy branch + uncond CFG branch
         # (an empty dict when cfg_policy is None — NoCachePolicy is stateless)
         uncond_pol = self.cfg_policy
@@ -568,13 +571,12 @@ class DiffusionServingEngine:
                     if uncond_pol is not None else {}),
         }
 
-        def slot_step(states, steps, xs, tvals, labels, scales, cfg_ws,
-                      ab_t, ab_n, y_c, y_u):
+        def slot_step(params, states, steps, xs, tvals, labels, scales,
+                      cfg_ws, ab_t, ab_n, y_c, y_u):
             """Shared tail of every tick program: vmapped per-slot policy
             step + traced per-slot DDIM update."""
-            eps, states = jax.vmap(apply_fn)(states, steps, xs, tvals,
-                                             labels, scales, cfg_ws,
-                                             y_c, y_u)
+            eps, states = jax.vmap(partial(apply_fn, params))(
+                states, steps, xs, tvals, labels, scales, cfg_ws, y_c, y_u)
             a_t = ab_t[:, None, None]
             a_n = ab_n[:, None, None]
             x0_hat = (xs - jnp.sqrt(1.0 - a_t) * eps) / jnp.sqrt(a_t)
@@ -587,18 +589,18 @@ class DiffusionServingEngine:
             per-slot text-table dict — an EMPTY dict on text-free engines,
             which contributes zero jit operand leaves, so their program
             signature is exactly the pre-text one."""
-            def tick(states, steps, xs, tvals, labels, nulls, null_vecs,
-                     null_mask, txt, scales, cfg_ws, ab_t, ab_n):
+            def tick(params, states, steps, xs, tvals, labels, nulls,
+                     null_vecs, null_mask, txt, scales, cfg_ws, ab_t, ab_n):
                 if mode == "full":
-                    y_c, y_u = backbone2_fn(xs, tvals, labels, nulls,
+                    y_c, y_u = backbone2_fn(params, xs, tvals, labels, nulls,
                                             null_vecs, null_mask, txt=txt)
                 elif mode == "cond":
-                    y_c = backbone_fn(xs, tvals, labels, txt=txt)
+                    y_c = backbone_fn(params, xs, tvals, labels, txt=txt)
                     y_u = jnp.zeros_like(xs)
                 else:
                     y_c = y_u = jnp.zeros_like(xs)
-                return slot_step(states, steps, xs, tvals, labels, scales,
-                                 cfg_ws, ab_t, ab_n, y_c, y_u)
+                return slot_step(params, states, steps, xs, tvals, labels,
+                                 scales, cfg_ws, ab_t, ab_n, y_c, y_u)
             return jax.jit(tick)
 
         def make_compact_tick(bucket: int):
@@ -607,18 +609,17 @@ class DiffusionServingEngine:
             scatter restores the S-row y_c / y_u layout (missing rows zero —
             they only reach branches the per-slot select discards).  All
             index operands are traced, so this compiles once per bucket."""
-            def tick(states, steps, xs, tvals, labels, nulls, null_vecs,
-                     null_mask, txt, scales, cfg_ws, ab_t, ab_n,
+            def tick(params, states, steps, xs, tvals, labels, nulls,
+                     null_vecs, null_mask, txt, scales, cfg_ws, ab_t, ab_n,
                      row_slot, row_uncond, row_dest):
                 if bucket == 0:
                     y_c = y_u = jnp.zeros_like(xs)
                 else:
-                    y_c, y_u = compact_backbone_fn(xs, tvals, labels, nulls,
-                                                   null_vecs, null_mask, txt,
-                                                   row_slot, row_uncond,
-                                                   row_dest)
-                return slot_step(states, steps, xs, tvals, labels, scales,
-                                 cfg_ws, ab_t, ab_n, y_c, y_u)
+                    y_c, y_u = compact_backbone_fn(
+                        params, xs, tvals, labels, nulls, null_vecs,
+                        null_mask, txt, row_slot, row_uncond, row_dest)
+                return slot_step(params, states, steps, xs, tvals, labels,
+                                 scales, cfg_ws, ab_t, ab_n, y_c, y_u)
             return jax.jit(tick)
 
         # program builders are kept either way: repro.analysis.ir re-traces
@@ -637,13 +638,12 @@ class DiffusionServingEngine:
         # batch outside vmap (repro.diffusion.pipeline.slot_want_fns), so a
         # signal-policy pool pays one batched embed and one device sync per
         # tick instead of per-slot singleton embeds and two syncs
-        self._want_all = jax.jit(
-            slot_want_fns(params, cfg, self.policy, cfg_policy))
+        self._want_all = jax.jit(slot_want_fns(cfg, self.policy, cfg_policy))
         # the pre-compile jit wrapper, kept for IR re-capture (warmup swaps
         # self._want_all for its Compiled executable)
         self._want_src = self._want_all
 
-        def build_text_tables(te, tm, ne, nm):
+        def build_text_tables(params, te, tm, ne, nm):
             """Per-slot cross-attn K/V over ALL layers at once, from the
             admission-time prompt / negative-prompt embedding tables.  Runs
             once per admission wave — text K/V is step-invariant, so no
@@ -726,12 +726,13 @@ class DiffusionServingEngine:
 
     # -- text conditioning ---------------------------------------------
     def _text_table_operands(self):
-        """Dummy (te, tm, ne, nm) operands shaped like one admission wave's
-        inputs to build_text_tables (text-enabled engines only)."""
+        """Dummy (params, te, tm, ne, nm) operands shaped like one
+        admission wave's inputs to build_text_tables (text-enabled engines
+        only)."""
         S, Lt = self.slots, self.cfg.dit_text_len
         te = jnp.zeros((S, Lt, self.cfg.d_model), jnp.float32)
         tm = jnp.zeros((S, Lt), bool)
-        return te, tm, te, tm
+        return self.params, te, tm, te, tm
 
     def _empty_txt(self):
         """An all-masked per-slot text-table dict (zero K/V, zero masks) —
@@ -753,16 +754,17 @@ class DiffusionServingEngine:
         if not self.text_enabled:
             return {}
         return self._text_tables(
+            self.params,
             jnp.asarray(self._txt_embed), jnp.asarray(self._txt_mask),
             jnp.asarray(self._neg_embed), jnp.asarray(self._neg_mask))
 
     # ------------------------------------------------------------------
     def _warmup_operands(self):
-        """Dummy device operands shaped exactly like a live tick's: the
-        13-tuple every tick program takes (the text-table dict is empty on
-        text-free engines — zero operand leaves), and the fused want
-        pass's 6-tuple (shared prefixes, so warmup and IR capture trace
-        the same shapes a session dispatches)."""
+        """Operands shaped exactly like a live tick's: the 14-tuple every
+        tick program takes (the model params first; the text-table dict is
+        empty on text-free engines — zero operand leaves), and the fused
+        want pass's 7-tuple (shared prefixes, so warmup and IR capture
+        trace the same shapes a session dispatches)."""
         S = self.slots
         T, D = self.tokens, self.in_dim
         xs = jnp.zeros((S, T, D), jnp.float32)
@@ -774,9 +776,9 @@ class DiffusionServingEngine:
         nv = jnp.zeros((S, self.cfg.d_model), jnp.float32)
         nm = jnp.zeros((S,), bool)
         ab = jnp.full((S,), 0.5, jnp.float32)
-        tick_args = (states, zi, xs, zf, zi, zi, nv, nm, self._empty_txt(),
-                     zf, zf, ab, ab)
-        want_args = (states, zi, xs, zf, zi, nm)
+        tick_args = (self.params, states, zi, xs, zf, zi, zi, nv, nm,
+                     self._empty_txt(), zf, zf, ab, ab)
+        want_args = (self.params, states, zi, xs, zf, zi, nm)
         return tick_args, want_args
 
     def _warmup_buckets(self) -> List[int]:
@@ -789,19 +791,6 @@ class DiffusionServingEngine:
             | {min(1 << (n - 1).bit_length(), S) for n in range(1, S + 1)}
             | {min(1 << (n - 1).bit_length(), 2 * S)
                for n in range(1, 2 * S + 1)})
-
-    def _param_leaf_specs(self):
-        """(shape, dtype-name) multiset of the model param leaves — the
-        consts a tick program is DECLARED to close over; anything else
-        big is closure-capture bloat (repro.analysis.ir const check).
-        Includes the conditioner's text-encoder leaves, so the
-        "text_encoder" program verifies under the same declaration."""
-        specs = tuple(
-            (tuple(getattr(l, "shape", ())), str(getattr(l, "dtype", "")))
-            for l in jax.tree_util.tree_leaves(self.params))
-        if self.conditioner is not None:
-            specs += tuple(self.conditioner.param_leaf_specs())
-        return specs
 
     def warmup(self, verify: bool = False) -> Dict[object, ProgramProfile]:
         """Compile every tick program on dummy inputs before serving, and
@@ -838,14 +827,12 @@ class DiffusionServingEngine:
                 self._run_ir_verification()
             return self.program_profile
         args, want_args = self._warmup_operands()
-        specs = self._param_leaf_specs() if verify else ()
         # the fused want pass also compiles on first use; without this a
         # state-dependent policy pays that compile inside its first live tick
         if self._static_plan is None or self._static_cfg_plan is None:
             if verify:
                 self._want_all, prof, ir = compile_program(
-                    self._want_src, *want_args, key="want", want_ir=True,
-                    declared_const_specs=specs)
+                    self._want_src, *want_args, key="want", want_ir=True)
                 self.program_ir["want"] = ir
             else:
                 self._want_all, prof = compile_program(
@@ -859,8 +846,7 @@ class DiffusionServingEngine:
             targs = self._text_table_operands()
             if verify:
                 self._text_tables, prof, ir = compile_program(
-                    self._text_tables, *targs, key="text_kv", want_ir=True,
-                    declared_const_specs=specs)
+                    self._text_tables, *targs, key="text_kv", want_ir=True)
                 self.program_ir["text_kv"] = ir
             else:
                 self._text_tables, prof = compile_program(
@@ -883,8 +869,7 @@ class DiffusionServingEngine:
                 if verify:
                     compiled, prof, ir = compile_program(
                         fn, *args, row_slot, row_uncond, row_dest,
-                        key=bucket, want_ir=True,
-                        declared_const_specs=specs)
+                        key=bucket, want_ir=True)
                     self.program_ir[bucket] = ir
                 else:
                     compiled, prof = compile_program(
@@ -900,8 +885,7 @@ class DiffusionServingEngine:
             for kind in ("full", "cond", "skip"):
                 if verify:
                     compiled, prof, ir = compile_program(
-                        self._ticks[kind], *args, key=kind, want_ir=True,
-                        declared_const_specs=specs)
+                        self._ticks[kind], *args, key=kind, want_ir=True)
                     self.program_ir[kind] = ir
                 else:
                     compiled, prof = compile_program(
@@ -914,7 +898,7 @@ class DiffusionServingEngine:
         # normal), the jit'd refill, and the harvest row gather+transfer.
         # Without this the first admission/harvest pays their compiles
         # mid-session — which the retrace sentinel rightly counts
-        xs, states = args[2], args[0]
+        states, xs = args[1], args[3]
         key = jax.random.fold_in(jax.random.PRNGKey(0), 0)
         noise = jax.random.normal(key, (self.tokens, self.in_dim))
         warm_xs, _ = self._refill(xs, states, 0, noise, self._fresh)
@@ -939,16 +923,14 @@ class DiffusionServingEngine:
         if self.program_ir:
             return self.program_ir
         from repro.obs.profiling import capture_ir
-        specs = self._param_leaf_specs()
         args, want_args = self._warmup_operands()
         if self._static_plan is None or self._static_cfg_plan is None:
             self.program_ir["want"] = capture_ir(
-                self._want_src, *want_args, key="want",
-                declared_const_specs=specs)
+                self._want_src, *want_args, key="want")
         if self.text_enabled:
             self.program_ir["text_kv"] = capture_ir(
                 jax.jit(self._text_tables_src), *self._text_table_operands(),
-                key="text_kv", declared_const_specs=specs)
+                key="text_kv")
             if self.conditioner is not None:
                 self.program_ir["text_encoder"] = \
                     self.conditioner.capture_ir()
@@ -960,13 +942,11 @@ class DiffusionServingEngine:
                 row_dest = jnp.full((bucket,), 2 * S, jnp.int32)
                 self.program_ir[bucket] = capture_ir(
                     self._make_compact_tick(bucket), *args, row_slot,
-                    row_uncond, row_dest, key=bucket,
-                    declared_const_specs=specs)
+                    row_uncond, row_dest, key=bucket)
         else:
             for kind in ("full", "cond", "skip"):
                 self.program_ir[kind] = capture_ir(
-                    self._make_tick(kind), *args, key=kind,
-                    declared_const_specs=specs)
+                    self._make_tick(kind), *args, key=kind)
         return self.program_ir
 
     def _run_ir_verification(self) -> None:
@@ -1088,7 +1068,7 @@ class DiffusionServingEngine:
                     self._static_cfg_plan[steps] & self._guided, None)
         # repro-lint: disable-next-line=host-sync-in-hot-path -- THE one priced per-tick sync: fused want-pass, surcharged in plan cost
         wc, wu, metric = jax.device_get(self._want_all(
-            states, jnp.asarray(steps), xs, jnp.asarray(tvals),
+            self.params, states, jnp.asarray(steps), xs, jnp.asarray(tvals),
             jnp.asarray(self._labels), jnp.asarray(self._guided)))
         wc, wu = np.asarray(wc, bool), np.asarray(wu, bool)
         if self._static_plan is not None:

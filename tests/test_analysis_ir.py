@@ -38,7 +38,8 @@ def test_host_callbacks_fire_on_debug_print():
         return x * 2
     issues = find_host_callbacks(jax.make_jaxpr(f)(jnp.zeros((4,))))
     assert issues and issues[0].category == "host-callback"
-    assert "debug_callback" in issues[0].message
+    # JAX 0.9 binds jax.debug.print as its own `debug_print` primitive
+    assert "debug_print" in issues[0].message
 
 
 def test_host_callbacks_silent_on_pure_program():
@@ -76,9 +77,12 @@ def test_const_bloat_fires_undeclared_and_respects_declaration():
         lambda x: x + jnp.asarray(big))(jnp.zeros((200, 200), jnp.float32))
     fired = find_const_bloat(closed)
     assert len(fired) == 1 and fired[0].category == "const-bloat"
-    # the same const declared as a model param leaf is budgeted, not bloat
-    assert find_const_bloat(closed, [((200, 200), "float32")]) == []
-    # a higher threshold also silences it
+    # the same array passed as a program operand (how engine programs take
+    # their model params) is no const at all, so nothing fires
+    operand = jax.make_jaxpr(lambda x, w: x + w)(
+        jnp.zeros((200, 200), jnp.float32), jnp.asarray(big))
+    assert operand.consts == [] and find_const_bloat(operand) == []
+    # a higher threshold also silences the closed-over one
     assert find_const_bloat(closed, threshold_bytes=1 << 20) == []
     assert 200 * 200 * 4 > DEFAULT_CONST_THRESHOLD
 

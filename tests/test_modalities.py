@@ -237,8 +237,8 @@ def test_pab_video_granularity_step0_exact(workloads):
     x = wl.noise(jax.random.PRNGKey(3), 1)
     t_vec = jnp.full((1,), 10.0, jnp.float32)
     eps, _ = den(den.init_state(1), 0, x, t_vec)
-    fwd, _ = backbone_fns(wl.params, wl.cfg)
-    ref = fwd(x, t_vec, jnp.zeros((1,), jnp.int32))
+    fwd, _ = backbone_fns(wl.cfg)
+    ref = fwd(wl.params, x, t_vec, jnp.zeros((1,), jnp.int32))
     np.testing.assert_allclose(np.asarray(eps), np.asarray(ref), atol=1e-5)
 
 
