@@ -1,0 +1,8 @@
+"""idle_share: share of the traced window in which no operation ran on
+the device, in percent."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * run.trace.idle_share
