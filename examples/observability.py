@@ -5,7 +5,8 @@ mixed-modality serving session.
 
 Serves a mixed image+video queue (TeaCache cond policy, FasterCacheCFG
 uncond reuse on the image pool) with the full repro.obs surface attached,
-then writes to OUTDIR (default /tmp/repro_obs):
+then writes to OUTDIR (default: a new directory under the system's temp
+directory, printed at the end):
 
   trace.json          Chrome/Perfetto trace — one process per modality
                       sub-pool, plan/backbone tracks, per-slot cache
@@ -18,6 +19,16 @@ then writes to OUTDIR (default /tmp/repro_obs):
   metrics.prom        Prometheus text exposition of every counter/gauge/
                       histogram the engines + schedulers published.
   metrics.json        the same registry as a JSON snapshot (+ event ring).
+  profile_*/          the jax.profiler trace the session ran under, in a
+                      directory made fresh for this run: each
+                      ServeSession.tick is an `engine.tick` span with its
+                      nine `engine.*` phase spans (admit, prepare, plan,
+                      upload, dispatch, wait, account, harvest, hooks) on
+                      the host plane, on the device ops' clock, their
+                      counts (requests, arrays, nbytes, bucket) as event
+                      stats.  The per-phase host time per tick is printed
+                      and reconciled with the registry's
+                      repro_engine_phase_seconds_total.
 
 It also prints warmup's per-program compile time + XLA-costed FLOPs and
 the measured redundancy ratio (FLOPs the caches avoided over the dense
@@ -25,11 +36,15 @@ FLOPs a no-cache pool would have dispatched), and reconciles the JSONL
 against ServingTelemetry: per-request computed-step counts must agree
 EXACTLY (tests/test_observability.py asserts the same).
 """
+import glob
 import json
 import os
 import sys
+import tempfile
 
+import jax
 import numpy as np
+from jax.profiler import ProfileData
 
 from repro.modalities import MixedModalityEngine, make_workload
 from repro.obs import (MetricsRegistry, TraceRecorder, flops_per_row,
@@ -40,8 +55,11 @@ NUM_STEPS = 8
 SLOTS = 2
 
 
-def main(outdir: str = "/tmp/repro_obs"):
-    os.makedirs(outdir, exist_ok=True)
+def main(outdir: str = ""):
+    if outdir:
+        os.makedirs(outdir, exist_ok=True)
+    else:
+        outdir = tempfile.mkdtemp(prefix="repro_obs_")
     workloads = {m: make_workload(m, smoke=True) for m in ("image", "video")}
     from repro.core import FasterCacheCFG
     pools = {
@@ -74,9 +92,13 @@ def main(outdir: str = "/tmp/repro_obs"):
                              seed=i, class_label=i % 5, modality=mods[i % 2],
                              cfg_scale=3.0 if mods[i % 2] == "image" else 0.0)
             for i in range(8)]
-    results = engine.serve(reqs, hooks={m: [rec] for m, rec
-                                        in recorders.items()},
-                           metrics=registry)
+    # a directory of this run's own, so the one trace read back below is
+    # the one this session wrote
+    profile_dir = tempfile.mkdtemp(prefix="profile_", dir=outdir)
+    with jax.profiler.trace(profile_dir):
+        results = engine.serve(reqs, hooks={m: [rec] for m, rec
+                                            in recorders.items()},
+                               metrics=registry)
     assert all(np.isfinite(r.x0).all() for r in results)
     for m, tele in engine.telemetry.pools.items():
         tele.publish(registry, modality=m)     # telemetry as a metrics view
@@ -137,12 +159,35 @@ def main(outdir: str = "/tmp/repro_obs"):
               f"({rr['flops_avoided']:.3e} of {rr['dense_flops']:.3e} "
               f"dense FLOPs avoided)")
 
+    # -- the engine's host spans, read back from the profiler trace ----
+    print("\n== engine.* host spans (profiler trace vs registry) ==")
+    (xplane,) = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                          recursive=True)
+    span_ms, span_n = {}, {}
+    for plane in ProfileData.from_file(xplane).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    span_ms[ev.name] = (span_ms.get(ev.name, 0.0)
+                                        + ev.duration_ns * 1e-6)
+                    span_n[ev.name] = span_n.get(ev.name, 0) + 1
+    phase_s = registry.counter("repro_engine_phase_seconds_total")
+    ticks = span_n["engine.tick"]
+    print(f"  {'engine.tick':16s} {span_ms['engine.tick'] / ticks:8.3f} "
+          f"ms per tick ({ticks} ticks)")
+    for name in sorted(span_ms, key=lambda n: -span_ms[n])[1:]:
+        counted = sum(phase_s.value(phase=name, modality=m) for m in pools)
+        print(f"  {name:16s} {span_ms[name] / ticks:8.3f} ms per tick "
+              f"(registry {1e3 * counted / ticks:8.3f})")
+
     s = engine.telemetry.summary()
     print(f"\nserved {s['requests']} requests "
           f"({s['throughput_rps']:.2f} req/s); wrote")
-    for name in ("trace.json", "cache_events.jsonl", "metrics.prom",
-                 "metrics.json"):
-        print(f"  {os.path.join(outdir, name)}")
+    for path in ("trace.json", "cache_events.jsonl", "metrics.prom",
+                 "metrics.json", profile_dir):
+        print(f"  {os.path.join(outdir, path)}")
     print("open trace.json at https://ui.perfetto.dev")
 
 

@@ -1,7 +1,7 @@
 """The one host-side clock for the serving stack.
 
 Every wall-time measurement in `repro.serving` and `repro.modalities` —
-engine tick device seconds, TickEvent plan_seconds, TelemetryWindow
+engine tick phase spans (repro.obs.span), TickEvent seconds, TelemetryWindow
 statistics, benchmark harness timings — must come from this module, not
 from ad-hoc `time.time()` / `time.perf_counter()` calls (the CI lint's
 clock-discipline rule, repro.analysis, enforces this for serving/ and

@@ -1,19 +1,29 @@
-"""TraceRecorder — structured tracing for the serving engines.
+"""Host spans and the TraceRecorder.
 
-A TickHook (`ServeSession(..., hooks=[recorder.observe])`, or one entry per
-modality for MixedModalityEngine) that turns the engine's TickEvent stream
-into two durable artifacts:
+`span` is the one way host code in this repo marks an interval of its own
+work.  It enters `jax.profiler.TraceAnnotation`, so whenever a profiler
+trace is running the interval lands on the trace's host plane, on the
+clock the device's ops are aligned with, carrying its counts as event
+stats; and it times the interval with `repro.obs.clock.monotonic`, adding
+the seconds to a caller's dict.  `ServeSession.tick` wraps each of its
+phases in one (`engine.tick` and its children `engine.admit` ...
+`engine.hooks`); the same seconds reach the
+`repro_engine_phase_seconds_total` counter and, all but `engine.hooks`'s,
+`TickEvent.phases` and the recorder below.
+
+TraceRecorder is a TickHook (`ServeSession(..., hooks=[recorder.observe])`,
+or one entry per modality for MixedModalityEngine) that turns the engine's
+TickEvent stream into two durable artifacts:
 
   * A Chrome/Perfetto `trace_event` JSON file (`write_chrome_trace`): one
-    process (pid) per modality sub-pool, with a "plan" track (host time
-    deciding each tick: the fused want pass + its device sync), a
-    "backbone" track (device time of the dispatched tick program,
-    annotated with kind / bucket / rows; the gather and scatter of the
-    row-compacted program are XLA-fused into that one program, so they
-    appear as instant markers on its span rather than separately-timed
-    phases), and one track per slot carrying cache-lifecycle spans:
-    admit -> per-tick compute / reuse / cond-only events annotated with
-    the policy's signal value and threshold -> finish or preempt.
+    process (pid) per modality sub-pool, with a "plan" track (the tick's
+    `engine.plan` span: the fused want pass + its device sync), a
+    "backbone" track (the tick program from its dispatch to
+    `block_until_ready`, annotated with kind / bucket / rows), and one
+    track per slot carrying cache-lifecycle spans: admit -> per-tick
+    compute / reuse / cond-only events annotated with the policy's signal
+    value and threshold -> finish or preempt.  Every start and duration
+    comes from the tick's `TickEvent.t_start` and `phases`.
     Open with https://ui.perfetto.dev or chrome://tracing.
 
   * A cache-event JSONL log (`write_cache_events`): one line per active
@@ -35,11 +45,51 @@ import json
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .clock import monotonic
 
-__all__ = ["TraceRecorder", "policy_signature", "load_cache_events",
-           "load_probes", "signal_trace_from_files", "validate_chrome_trace"]
+__all__ = ["span", "TraceRecorder", "policy_signature",
+           "load_cache_events", "load_probes", "signal_trace_from_files",
+           "validate_chrome_trace"]
+
+
+class span:
+    """A host span: a profiler annotation timed on `monotonic()`.
+
+        with span("engine.upload", sink, arrays=3) as s:
+            ...
+            s.count(nbytes=n)
+
+    Inside a running profiler trace the span is an event on the host
+    plane named `name`, its counts read back as event stats; outside one
+    it costs the annotation's check and two clock reads.  After the block
+    `seconds` holds the interval, and `sink[name]` (when a dict is given)
+    has grown by it."""
+
+    __slots__ = ("name", "sink", "t0", "seconds", "_ann")
+
+    def __init__(self, name: str, sink: Optional[Dict[str, float]] = None,
+                 **counts):
+        self.name = name
+        self.sink = sink
+        self.t0 = self.seconds = 0.0
+        self._ann = TraceAnnotation(name, **counts)
+
+    def count(self, **counts) -> None:
+        """Attach counts known only once the span's work is done."""
+        self._ann.set_metadata(**counts)
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self.t0 = monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = monotonic() - self.t0
+        self._ann.__exit__(*exc)
+        if self.sink is not None:
+            self.sink[self.name] = self.sink.get(self.name, 0.0) + self.seconds
 
 
 def policy_signature(policy) -> Dict[str, Optional[float]]:
@@ -127,44 +177,44 @@ class TraceRecorder:
     _TID_PLAN, _TID_BACKBONE, _TID_SLOT0 = 0, 1, 2
 
     # -- the hook ------------------------------------------------------
+    @staticmethod
+    def _phase(event, name: str) -> tuple:
+        """(start, seconds) of one phase of the tick: the phases run one
+        after another from `t_start`, in the order `phases` holds them."""
+        t = float(event.t_start)
+        for k, s in event.phases.items():
+            if k == name:
+                return t, float(s)
+            t += s
+        raise KeyError(f"tick {event.tick} has no phase {name!r}")
+
     def observe(self, event) -> None:
         """TickHook entry point: fold one TickEvent into both artifacts."""
-        t_now = monotonic()
         pid = self._pid(event.modality)
-        seconds = float(event.seconds)
-        plan_s = float(event.plan_seconds)
-        t_start = t_now - seconds - plan_s       # tick began planning here
-        t_dev = t_now - seconds                  # device program began here
+        t_start = float(event.t_start)
+        t_plan, plan_s = self._phase(event, "engine.plan")
+        t_dev, _ = self._phase(event, "engine.dispatch")
+        seconds = float(event.seconds)           # dispatch + wait
+        t_harvest, harvest_s = self._phase(event, "engine.harvest")
+        t_done = t_harvest + harvest_s
         bucket = int(event.rows_computed) + int(event.rows_padding)
 
         if plan_s > 0.0:
             self.events.append({
                 "ph": "X", "name": "plan", "cat": "plan", "pid": pid,
                 "tid": self._tid(pid, self._TID_PLAN, "plan"),
-                "ts": self._us(t_start), "dur": plan_s * 1e6,
+                "ts": self._us(t_plan), "dur": plan_s * 1e6,
                 "args": {"tick": event.tick,
                          "on_device": event.metric is not None}})
-        tid_bb = self._tid(pid, self._TID_BACKBONE, "backbone")
         self.events.append({
             "ph": "X", "name": f"tick:{event.kind}", "cat": "backbone",
-            "pid": pid, "tid": tid_bb,
+            "pid": pid,
+            "tid": self._tid(pid, self._TID_BACKBONE, "backbone"),
             "ts": self._us(t_dev), "dur": seconds * 1e6,
             "args": {"tick": event.tick, "kind": event.kind,
                      "rows_computed": int(event.rows_computed),
                      "rows_padding": int(event.rows_padding),
                      "bucket": bucket}})
-        if event.kind != "skip":
-            # gather/scatter are fused INTO the tick program by XLA — no
-            # separate device timing exists, so they are instant markers
-            # bracketing the span, not separately-timed phases
-            self.events.append({
-                "ph": "i", "name": "gather", "cat": "backbone", "pid": pid,
-                "tid": tid_bb, "ts": self._us(t_dev), "s": "t",
-                "args": {"rows": int(event.rows_computed)}})
-            self.events.append({
-                "ph": "i", "name": "scatter", "cat": "backbone", "pid": pid,
-                "tid": tid_bb, "ts": self._us(t_now), "s": "t",
-                "args": {"rows": int(event.rows_computed)}})
 
         rids = np.asarray(event.request_ids)
         active = np.asarray(event.active, bool)
@@ -233,7 +283,7 @@ class TraceRecorder:
 
         # -- finishes close their slot spans ----------------------------
         for rec in event.finished:
-            self._close(event.modality, pid, t_now, rec.request_id,
+            self._close(event.modality, pid, t_done, rec.request_id,
                         preempted=False,
                         computed_steps=int(rec.computed_steps))
         self.ticks_seen += 1

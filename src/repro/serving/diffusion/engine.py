@@ -65,7 +65,9 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Callable, Dict, List, Optional, Sequence, Union
+from types import MappingProxyType
+from typing import (Callable, Dict, List, Mapping, Optional, Sequence,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +80,7 @@ from repro.diffusion.pipeline import slot_compact_denoise_fns, slot_want_fns
 from repro.models import dit
 from repro.obs.clock import monotonic
 from repro.obs.profiling import ProgramIR, ProgramProfile, compile_program
+from repro.obs.trace import span
 
 from .scheduler import DiffusionRequest, SlotScheduler
 from .telemetry import RequestRecord, ServingTelemetry
@@ -150,17 +153,25 @@ class TickEvent:
     `metric` is the per-slot `CachePolicy.want_metric` scalar (the value
     the refresh decision thresholded on); None when the engine planned the
     tick from a host-side static schedule (no device metric exists).
-    `plan_seconds` is the host time spent DECIDING the tick (the fused
-    want pass + its device_get sync for state-dependent policies; ~0 for
-    static schedules planned on the host) — the overhead the online
-    tuner's cost model charges non-static candidates per step.
+    `plan_seconds` is the host time spent DECIDING the tick, the
+    `engine.plan` phase (the fused want pass + its device_get sync for
+    state-dependent policies; ~0 for static schedules planned on the
+    host) — the overhead the online tuner's cost model charges non-static
+    candidates per step.  `seconds` is host time from dispatch to
+    `block_until_ready` (`engine.dispatch` + `engine.wait`), not a device
+    time.  `t_start` is the `monotonic()` reading at which the tick
+    began, and `phases` a read-only mapping of the host seconds of each
+    phase span that closed before the event was built, in the order they
+    ran: every phase ServeSession.tick lists but `engine.hooks`, which
+    holds the hook calls themselves (its seconds reach the profiler trace
+    and the registry only).
     `latents` is the pre-tick (slots, tokens, in_dim) latent batch — only
     populated when the session was started with `capture_latents=True`
     (it costs a device transfer per tick)."""
     tick: int
     modality: str
     kind: str                       # "full" | "cond" | "skip"
-    seconds: float                  # device time of this tick's program
+    seconds: float                  # host s, dispatch to block_until_ready
     rows_computed: int
     rows_padding: int
     active: np.ndarray              # (S,) bool
@@ -176,6 +187,8 @@ class TickEvent:
     latents: Optional[np.ndarray] = None    # (S, T, D) pre-tick, opt-in
     admitted: List[DiffusionRequest] = field(default_factory=list)
     finished: List[RequestRecord] = field(default_factory=list)
+    t_start: float = 0.0            # monotonic() when the tick began
+    phases: Mapping[str, float] = field(default_factory=dict)
 
 
 #: observer hook signature: called once per tick, must not mutate the engine
@@ -309,155 +322,193 @@ class ServeSession:
     # ------------------------------------------------------------------
     def tick(self) -> None:
         """One engine tick: refill free slots, plan the wanted rows,
-        dispatch the matching program, advance and harvest."""
+        dispatch the matching program, advance and harvest.
+
+        The tick is one `engine.tick` span holding nine phase spans, one
+        after another: admit, prepare, plan, upload, dispatch, wait,
+        account, harvest, hooks (each `engine.<phase>`; repro.obs.span).
+        The hooks get the first eight in the TickEvent's `phases`; with a
+        registry, all nine are published once `engine.hooks` has closed."""
         if self._finished:
             raise RuntimeError("session already finished; the engine's "
                                "per-slot tables may belong to a new session")
         eng, sched, tele = self.engine, self.sched, self.tele
         now = monotonic
         T, D = eng.tokens, eng.in_dim
+        phases: Dict[str, float] = {}
+        with span("engine.tick", tick=self.ticks,
+                  modality=self.modality) as whole:
+            # -- refill free slots from the queue (phase-aligned) -------
+            with span("engine.admit", phases) as sp:
+                admitted = sched.admit(self.ticks)
+                for slot, req in admitted:
+                    noise = jax.random.normal(request_noise_key(req), (T, D))
+                    self.xs, self.states = eng._refill(
+                        self.xs, self.states, slot.index, noise, eng._fresh)
+                    eng._install_request(slot.index, req)
+                    rec = self.recs[req.request_id]
+                    rec.admit_time = now()
+                    rec.admit_tick = self.ticks
+                    rec.slot = slot.index
+                if admitted:
+                    self._null_vecs = jnp.asarray(eng._null_vecs)
+                    self._null_mask = jnp.asarray(eng._null_mask)
+                    # one text_kv pass per admission wave (not per tick):
+                    # project the newly installed prompt embeddings to
+                    # per-slot K/V tables
+                    self._txt = eng._build_text_tables()
+                sp.count(requests=len(admitted))
 
-        # -- refill free slots from the queue (phase-aligned) -------
-        admitted = sched.admit(self.ticks)
-        for slot, req in admitted:
-            noise = jax.random.normal(request_noise_key(req), (T, D))
-            self.xs, self.states = eng._refill(self.xs, self.states,
-                                               slot.index, noise, eng._fresh)
-            eng._install_request(slot.index, req)
-            rec = self.recs[req.request_id]
-            rec.admit_time = now()
-            rec.admit_tick = self.ticks
-            rec.slot = slot.index
-        if admitted:
-            self._null_vecs = jnp.asarray(eng._null_vecs)
-            self._null_mask = jnp.asarray(eng._null_mask)
-            # one text_kv pass per admission wave (not per tick): project
-            # the newly installed prompt embeddings to per-slot K/V tables
-            self._txt = eng._build_text_tables()
+            with span("engine.prepare", phases):
+                active = np.asarray(sched.active_mask())
+                steps = np.asarray(sched.steps(), np.int32)
+                idx = np.minimum(steps, eng.max_steps - 1)
+                rows = np.arange(eng.slots)
+                tvals = eng._tv[rows, idx]
+                ab_t = eng._ab[rows, idx]
+                ab_n = eng._ab[rows, idx + 1]
+                # per-slot trajectory-progress weight for FasterCacheCFG
+                cfg_ws = (idx.astype(np.float32)
+                          / np.maximum(eng._nsteps - 1, 1))
+                # per-slot request ids + optional pre-tick latents,
+                # captured BEFORE the device tick / harvest mutate them
+                rids = np.asarray([s.request.request_id if s.busy else -1
+                                   for s in sched.slots], np.int64)
+                latents = (np.asarray(self.xs) if self.capture_latents
+                           else None)
+            whole.count(active=int(active.sum()))
 
-        active = np.asarray(sched.active_mask())
-        steps = np.asarray(sched.steps(), np.int32)
-        idx = np.minimum(steps, eng.max_steps - 1)
-        rows = np.arange(eng.slots)
-        tvals = eng._tv[rows, idx]
-        ab_t = eng._ab[rows, idx]
-        ab_n = eng._ab[rows, idx + 1]
-        # per-slot trajectory-progress weight for FasterCacheCFG's blend
-        cfg_ws = idx.astype(np.float32) / np.maximum(eng._nsteps - 1, 1)
+            with span("engine.plan", phases) as sp:
+                want_c, want_u, metric = eng._plan_all(self.states, idx,
+                                                       self.xs, tvals)
+                sp.count(on_device=int(metric is not None))
 
-        # per-slot request ids + optional pre-tick latents, captured BEFORE
-        # the device tick / harvest mutate them (for the TickEvent)
-        rids = np.asarray([s.request.request_id if s.busy else -1
-                           for s in sched.slots], np.int64)
-        latents = np.asarray(self.xs) if self.capture_latents else None
+            with span("engine.upload", phases) as sp:
+                want_c = want_c & active
+                want_u = want_u & active
+                n_c, n_u = int(want_c.sum()), int(want_u.sum())
+                if n_u:
+                    kind = "full"      # some slot refreshes its uncond cache
+                elif n_c:
+                    kind = "cond"      # cond-branch rows only
+                else:
+                    kind = "skip"
+                # rows a dense whole-pool tick of this kind dispatches (the
+                # dense engine's actual batch; also what row compaction
+                # saves against)
+                dense_rows = {"full": 2 * eng.slots, "cond": eng.slots,
+                              "skip": 0}[kind]
+                host = [idx, tvals, eng._labels, eng._nulls, eng._scales,
+                        cfg_ws, ab_t, ab_n]
+                if eng.row_compaction:
+                    bucket, *row_plan = compact_rows(want_c, want_u,
+                                                     eng.slots)
+                    host += row_plan
+                else:
+                    bucket = dense_rows
+                (idx_d, tvals_d, labels_d, nulls_d, scales_d, cfg_ws_d,
+                 ab_t_d, ab_n_d, *rows_d) = [jnp.asarray(a) for a in host]
+                args = (eng.params, self.states, idx_d, self.xs, tvals_d,
+                        labels_d, nulls_d, self._null_vecs, self._null_mask,
+                        self._txt, scales_d, cfg_ws_d, ab_t_d, ab_n_d,
+                        *rows_d)
+                sp.count(arrays=len(host),
+                         nbytes=sum(a.nbytes for a in host))
 
-        t_plan = now()
-        want_c, want_u, metric = eng._plan_all(self.states, idx, self.xs,
-                                               tvals)
-        plan_s = now() - t_plan
-        want_c = want_c & active
-        want_u = want_u & active
-        n_c, n_u = int(want_c.sum()), int(want_u.sum())
-        if n_u:
-            kind = "full"          # some slot refreshes its uncond cache
-        elif n_c:
-            kind = "cond"          # cond-branch rows only
-        else:
-            kind = "skip"
-        # rows a dense whole-pool tick of this kind dispatches (the dense
-        # engine's actual batch; also what row compaction saves against)
-        dense_rows = {"full": 2 * eng.slots, "cond": eng.slots,
-                      "skip": 0}[kind]
-        args = (eng.params, self.states, jnp.asarray(idx), self.xs,
-                jnp.asarray(tvals), jnp.asarray(eng._labels),
-                jnp.asarray(eng._nulls),
-                self._null_vecs, self._null_mask, self._txt,
-                jnp.asarray(eng._scales), jnp.asarray(cfg_ws),
-                jnp.asarray(ab_t), jnp.asarray(ab_n))
-        if eng.row_compaction:
-            bucket, row_slot, row_uncond, row_dest = compact_rows(
-                want_c, want_u, eng.slots)
-            t0 = now()
-            self.xs, self.states = eng._compact_tick(bucket)(
-                *args, jnp.asarray(row_slot), jnp.asarray(row_uncond),
-                jnp.asarray(row_dest))
-            self.xs.block_until_ready()
-            tick_s = now() - t0
-            rows_done = n_c + n_u
-            rows_pad = bucket - rows_done
-            tele.record_tick(kind, tick_s,
-                             rows_computed=rows_done,
-                             rows_padding=rows_pad,
-                             rows_saved=dense_rows - rows_done)
-        else:
-            t0 = now()
-            self.xs, self.states = eng._ticks[kind](*args)
-            self.xs.block_until_ready()
-            tick_s = now() - t0
-            rows_done, rows_pad = dense_rows, 0
-            tele.record_tick(kind, tick_s, rows_computed=dense_rows)
-        # uncond accounting in rows actually refreshing a CFG cache: a
-        # dense full tick used to add `slots`, over-counting inactive and
-        # unguided slots into the autotuner's row cost
-        tele.uncond_rows_computed += n_u
-        tele.uncond_rows_saved += int(
-            (active & eng._guided & ~want_u).sum())
+            with span("engine.dispatch", phases, bucket=bucket):
+                program = (eng._compact_tick(bucket) if eng.row_compaction
+                           else eng._ticks[kind])
+                self.xs, self.states = program(*args)
+            with span("engine.wait", phases):
+                self.xs.block_until_ready()
+            tick_s = phases["engine.dispatch"] + phases["engine.wait"]
 
-        for slot in sched.slots:
-            if slot.busy and want_c[slot.index]:
-                self.recs[slot.request.request_id].computed_steps += 1
-            if slot.busy and want_u[slot.index]:
-                self.recs[slot.request.request_id].uncond_computed_steps += 1
+            with span("engine.account", phases):
+                if eng.row_compaction:
+                    rows_done = n_c + n_u
+                    rows_pad = bucket - rows_done
+                    tele.record_tick(kind, tick_s,
+                                     rows_computed=rows_done,
+                                     rows_padding=rows_pad,
+                                     rows_saved=dense_rows - rows_done)
+                else:
+                    rows_done, rows_pad = dense_rows, 0
+                    tele.record_tick(kind, tick_s, rows_computed=dense_rows)
+                # uncond accounting in rows actually refreshing a CFG
+                # cache: a dense full tick used to add `slots`, over-
+                # counting inactive and unguided slots into the
+                # autotuner's row cost
+                tele.uncond_rows_computed += n_u
+                tele.uncond_rows_saved += int(
+                    (active & eng._guided & ~want_u).sum())
+                for slot in sched.slots:
+                    if slot.busy and want_c[slot.index]:
+                        self.recs[slot.request.request_id].computed_steps += 1
+                    if slot.busy and want_u[slot.index]:
+                        self.recs[
+                            slot.request.request_id].uncond_computed_steps += 1
 
-        # -- advance + harvest finished slots -----------------------
-        sched.advance()
-        finished: List[RequestRecord] = []
-        for slot, req in sched.harvest():
-            rec = self.recs[req.request_id]
-            rec.finish_time = now()
-            rec.finish_tick = self.ticks + 1
-            tele.finish_request(rec)
-            finished.append(rec)
-            self.results[req.request_id] = DiffusionResult(
-                req.request_id, np.asarray(self.xs[slot.index]), rec)
+            # -- advance + harvest finished slots -----------------------
+            with span("engine.harvest", phases) as sp:
+                sched.advance()
+                finished: List[RequestRecord] = []
+                for slot, req in sched.harvest():
+                    rec = self.recs[req.request_id]
+                    rec.finish_time = now()
+                    rec.finish_tick = self.ticks + 1
+                    tele.finish_request(rec)
+                    finished.append(rec)
+                    self.results[req.request_id] = DiffusionResult(
+                        req.request_id, np.asarray(self.xs[slot.index]), rec)
+                sp.count(requests=len(finished))
 
-        if self.metrics is not None:
-            self._publish_tick(kind, tick_s, plan_s, rows_done, rows_pad,
-                               dense_rows - rows_done
-                               if eng.row_compaction else 0,
-                               n_u, int(active.sum()), len(finished))
+            with span("engine.hooks", phases):
+                if self.hooks:
+                    event = TickEvent(
+                        tick=self.ticks, modality=self.modality, kind=kind,
+                        seconds=tick_s, plan_seconds=phases["engine.plan"],
+                        rows_computed=rows_done,
+                        rows_padding=rows_pad, active=active,
+                        request_ids=rids, steps=steps,
+                        tvals=np.asarray(tvals, np.float32),
+                        labels=eng._labels.copy(),
+                        guided=eng._guided.copy(),
+                        want_cond=want_c, want_uncond=want_u,
+                        metric=metric, latents=latents,
+                        admitted=[req for _, req in admitted],
+                        finished=finished, t_start=whole.t0,
+                        phases=MappingProxyType(dict(phases)))
+                    for hook in self.hooks:
+                        hook(event)
+            if self.metrics is not None:
+                self._publish_tick(kind, tick_s, rows_done, rows_pad,
+                                   dense_rows - rows_done
+                                   if eng.row_compaction else 0,
+                                   n_u, int(active.sum()), len(finished),
+                                   phases)
+            self.ticks += 1
 
-        if self.hooks:
-            event = TickEvent(
-                tick=self.ticks, modality=self.modality, kind=kind,
-                seconds=tick_s, plan_seconds=plan_s,
-                rows_computed=rows_done,
-                rows_padding=rows_pad, active=active, request_ids=rids,
-                steps=steps, tvals=np.asarray(tvals, np.float32),
-                labels=eng._labels.copy(), guided=eng._guided.copy(),
-                want_cond=want_c, want_uncond=want_u,
-                metric=metric, latents=latents,
-                admitted=[req for _, req in admitted], finished=finished)
-            for hook in self.hooks:
-                hook(event)
-
-        self.ticks += 1
-
-    def _publish_tick(self, kind: str, tick_s: float, plan_s: float,
-                      rows_done: int, rows_pad: int, rows_saved: int,
-                      n_u: int, occupancy: int, finished: int) -> None:
+    def _publish_tick(self, kind: str, tick_s: float, rows_done: int,
+                      rows_pad: int, rows_saved: int, n_u: int,
+                      occupancy: int, finished: int,
+                      phases: Mapping[str, float]) -> None:
         """One tick's worth of registry updates (metric names follow
-        repro_<subsystem>_<metric>_<unit>, labels carry dimensions)."""
+        repro_<subsystem>_<metric>_<unit>, labels carry dimensions),
+        made after the tick's `engine.hooks` span closed, so `phases`
+        holds all nine."""
         m, mod = self.metrics, self.modality
+        phase_s = m.counter("repro_engine_phase_seconds_total",
+                            "host seconds of each phase span of "
+                            "ServeSession.tick")
+        for name, sec in phases.items():
+            phase_s.inc(sec, phase=name, modality=mod)
         m.counter("repro_engine_ticks_total",
                   "engine ticks by program kind").inc(
             kind=kind, modality=mod)
         m.counter("repro_engine_tick_seconds_total",
-                  "device seconds of dispatched tick programs").inc(
+                  "host seconds from dispatch to block_until_ready of "
+                  "tick programs").inc(
             tick_s, kind=kind, modality=mod)
-        m.counter("repro_engine_plan_seconds_total",
-                  "host seconds spent deciding ticks (want pass)").inc(
-            plan_s, modality=mod)
         m.counter("repro_engine_rows_computed_total",
                   "backbone rows carrying real per-slot work").inc(
             rows_done, modality=mod)
@@ -475,7 +526,8 @@ class ServeSession:
         m.gauge("repro_engine_occupancy_slots",
                 "busy slots at the latest tick").set(occupancy, modality=mod)
         m.histogram("repro_engine_tick_seconds",
-                    "device tick time distribution").observe(
+                    "host seconds from dispatch to block_until_ready, "
+                    "per tick").observe(
             tick_s, modality=mod)
 
     # ------------------------------------------------------------------
