@@ -17,7 +17,8 @@ TickEvent stream into two durable artifacts:
 
   * A Chrome/Perfetto `trace_event` JSON file (`write_chrome_trace`): one
     process (pid) per modality sub-pool, with a "plan" track (the tick's
-    `engine.plan` span: the fused want pass + its device sync), a
+    `engine.plan` span: the read of the plan the previous tick program
+    carried, or the fused want pass + its device sync), a
     "backbone" track (the tick program from its dispatch to
     `block_until_ready`, annotated with kind / bucket / rows), and one
     track per slot carrying cache-lifecycle spans: admit -> per-tick

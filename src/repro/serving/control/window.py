@@ -38,7 +38,7 @@ class TickStat:
     kind: str                 # "full" | "cond" | "skip"
     seconds: float
     plan_seconds: float       # host time spent deciding the tick
-    planned_on_device: bool   # True when the want pass synced the device
+    planned_on_device: bool   # True when the plan came from the device
     rows_computed: int
     rows_padding: int
     occupancy: int            # busy slots this tick
@@ -114,7 +114,7 @@ class TelemetryWindow:
         return max(int(round(sum(t.occupancy for t in back) / len(back))), 1)
 
     def plan_time_ms(self) -> float:
-        """Mean host ms per tick spent on the fused want pass, over ticks
+        """Mean host ms per tick spent planning the tick, over ticks
         the engine had to plan ON DEVICE (metric present).  Static-schedule
         policies plan on the host for ~free, so those ticks are excluded —
         and 0.0 is returned while the window holds no device-planned ticks.

@@ -257,11 +257,13 @@ def price_and_pick(evaluated: Sequence[TunedPolicy], sla: SLA,
     to the highest-PSNR candidate (marked infeasible) when nothing meets
     the SLA, so the server keeps serving on an over-tight objective.
 
-    plan_ms: measured host cost per tick of the fused want pass + its
-    device sync (`TelemetryWindow.plan_time_ms()`), charged per step to
-    candidates without a host-side static plan.  Row counts alone misprice
+    plan_ms: measured host cost per tick of planning on the device (the
+    carried plan's read, or the fused want pass + its device sync on a
+    session's first tick and on admission ticks;
+    `TelemetryWindow.plan_time_ms()`), charged per step to candidates
+    without a host-side static plan.  Row counts alone misprice
     state-dependent policies — a TeaCache tick that skips every row still
-    pays a device round trip to find that out — and this surcharge is what
+    pays for its device plan — and this surcharge is what
     lets the online tuner prefer a calibrated static schedule over a
     dynamic policy with fewer rows but slower wall-clock ticks."""
     priced: List[TunedPolicy] = []

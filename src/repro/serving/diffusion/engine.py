@@ -151,13 +151,16 @@ class TickEvent:
     slots not active this tick carry request_id -1.
 
     `metric` is the per-slot `CachePolicy.want_metric` scalar (the value
-    the refresh decision thresholded on); None when the engine planned the
-    tick from a host-side static schedule (no device metric exists).
+    the refresh decision thresholded on; 0 on free slots); None when the
+    engine planned the tick from a host-side static schedule (no device
+    metric exists).
     `plan_seconds` is the host time spent DECIDING the tick, the
-    `engine.plan` phase (the fused want pass + its device_get sync for
-    state-dependent policies; ~0 for static schedules planned on the
-    host) — the overhead the online tuner's cost model charges non-static
-    candidates per step.  `seconds` is host time from dispatch to
+    `engine.plan` phase: for state-dependent policies the read of the plan
+    the previous tick program computed, or, on a session's first tick and
+    on ticks whose admission refilled a slot, the fused want pass and its
+    device_get sync; ~0 for static schedules planned on the host — the
+    overhead the online tuner's cost model charges non-static candidates
+    per step.  `seconds` is host time from dispatch to
     `block_until_ready` (`engine.dispatch` + `engine.wait`), not a device
     time.  `t_start` is the `monotonic()` reading at which the tick
     began, and `phases` a read-only mapping of the host seconds of each
@@ -268,6 +271,10 @@ class ServeSession:
         # a slot's prompt — text is step-invariant, so every tick reuses
         # them verbatim ({} on text-free engines: zero operand leaves)
         self._txt = engine._build_text_tables()
+        # the (want_cond, want_uncond, metric) device triple the last tick
+        # program computed for the next tick; None until a tick ran, and
+        # always on engines with a host static plan
+        self._plan = None
         self.results: Dict[int, DiffusionResult] = {}
         self.ticks = 0
         self._finished = False
@@ -379,13 +386,24 @@ class ServeSession:
             whole.count(active=int(active.sum()))
 
             with span("engine.plan", phases) as sp:
-                want_c, want_u, metric = eng._plan_all(self.states, idx,
-                                                       self.xs, tvals)
-                sp.count(on_device=int(metric is not None))
+                # the previous tick program planned this tick, unless
+                # admission refilled a slot since
+                plan = None if admitted else self._plan
+                self._plan = None
+                want_c, want_u, metric = eng._plan_all(
+                    self.states, idx, self.xs, tvals, plan)
+                source = ("static" if not eng._device_plan else
+                          "carried" if plan is not None else "want_pass")
+                sp.count(on_device=int(metric is not None),
+                         carried=int(plan is not None))
 
             with span("engine.upload", phases) as sp:
                 want_c = want_c & active
                 want_u = want_u & active
+                if metric is not None:
+                    # a free slot has no request to measure (and a carried
+                    # plan evaluated it at another step than a want pass)
+                    metric = np.where(active, metric, np.float32(0.0))
                 n_c, n_u = int(want_c.sum()), int(want_u.sum())
                 if n_u:
                     kind = "full"      # some slot refreshes its uncond cache
@@ -400,6 +418,11 @@ class ServeSession:
                               "skip": 0}[kind]
                 host = [idx, tvals, eng._labels, eng._nulls, eng._scales,
                         cfg_ws, ab_t, ab_n]
+                if eng._device_plan:
+                    # the next tick's timesteps, for the tick program's
+                    # own plan of that tick
+                    host.append(eng._tv[rows, np.minimum(
+                        idx + 1, eng.max_steps - 1)])
                 if eng.row_compaction:
                     bucket, *row_plan = compact_rows(want_c, want_u,
                                                      eng.slots)
@@ -407,18 +430,24 @@ class ServeSession:
                 else:
                     bucket = dense_rows
                 (idx_d, tvals_d, labels_d, nulls_d, scales_d, cfg_ws_d,
-                 ab_t_d, ab_n_d, *rows_d) = [jnp.asarray(a) for a in host]
+                 ab_t_d, ab_n_d, *rest_d) = [jnp.asarray(a) for a in host]
                 args = (eng.params, self.states, idx_d, self.xs, tvals_d,
                         labels_d, nulls_d, self._null_vecs, self._null_mask,
                         self._txt, scales_d, cfg_ws_d, ab_t_d, ab_n_d,
-                        *rows_d)
+                        *rest_d)
                 sp.count(arrays=len(host),
                          nbytes=sum(a.nbytes for a in host))
 
             with span("engine.dispatch", phases, bucket=bucket):
                 program = (eng._compact_tick(bucket) if eng.row_compaction
                            else eng._ticks[kind])
-                self.xs, self.states = program(*args)
+                self.xs, self.states, *nxt = program(*args)
+                if nxt:
+                    # the next tick's plan reaches the host while this
+                    # tick's wait runs, not after the next tick's dispatch
+                    self._plan = nxt[0]
+                    for a in self._plan:
+                        a.copy_to_host_async()
             with span("engine.wait", phases):
                 self.xs.block_until_ready()
             tick_s = phases["engine.dispatch"] + phases["engine.wait"]
@@ -485,17 +514,19 @@ class ServeSession:
                                    dense_rows - rows_done
                                    if eng.row_compaction else 0,
                                    n_u, int(active.sum()), len(finished),
-                                   phases)
+                                   phases, source)
             self.ticks += 1
 
     def _publish_tick(self, kind: str, tick_s: float, rows_done: int,
                       rows_pad: int, rows_saved: int, n_u: int,
                       occupancy: int, finished: int,
-                      phases: Mapping[str, float]) -> None:
+                      phases: Mapping[str, float], source: str) -> None:
         """One tick's worth of registry updates (metric names follow
         repro_<subsystem>_<metric>_<unit>, labels carry dimensions),
         made after the tick's `engine.hooks` span closed, so `phases`
-        holds all nine."""
+        holds all nine.  `source` says where the tick's plan came from:
+        "carried" (the previous tick program), "want_pass" or
+        "static"."""
         m, mod = self.metrics, self.modality
         phase_s = m.counter("repro_engine_phase_seconds_total",
                             "host seconds of each phase span of "
@@ -505,6 +536,10 @@ class ServeSession:
         m.counter("repro_engine_ticks_total",
                   "engine ticks by program kind").inc(
             kind=kind, modality=mod)
+        m.counter("repro_engine_plans_total",
+                  "tick plans by source: carried from the previous tick "
+                  "program, the standalone want pass, or a host static "
+                  "schedule").inc(source=source, modality=mod)
         m.counter("repro_engine_tick_seconds_total",
                   "host seconds from dispatch to block_until_ready of "
                   "tick programs").inc(
@@ -623,17 +658,33 @@ class DiffusionServingEngine:
                     if uncond_pol is not None else {}),
         }
 
+        # cond want + uncond want + trace metric in ONE pass — the TeaCache
+        # signal is computed over the whole slot batch outside vmap
+        # (repro.diffusion.pipeline.slot_want_fns)
+        want_all_fn = slot_want_fns(cfg, self.policy, cfg_policy)
+
         def slot_step(params, states, steps, xs, tvals, labels, scales,
-                      cfg_ws, ab_t, ab_n, y_c, y_u):
+                      cfg_ws, ab_t, ab_n, y_c, y_u, *tv_next):
             """Shared tail of every tick program: vmapped per-slot policy
-            step + traced per-slot DDIM update."""
+            step + traced per-slot DDIM update.  Given the next tick's
+            timesteps `tv_next` (engines that plan on the device), it also
+            returns the next tick's (want_cond, want_uncond, metric): the
+            want pass over the updated states and latents at step
+            min(steps + 1, max_steps - 1), guided where scale > 0 — valid
+            for every slot still busy next tick, as long as no admission
+            refills a slot in between."""
             eps, states = jax.vmap(partial(apply_fn, params))(
                 states, steps, xs, tvals, labels, scales, cfg_ws, y_c, y_u)
             a_t = ab_t[:, None, None]
             a_n = ab_n[:, None, None]
             x0_hat = (xs - jnp.sqrt(1.0 - a_t) * eps) / jnp.sqrt(a_t)
             x_next = jnp.sqrt(a_n) * x0_hat + jnp.sqrt(1.0 - a_n) * eps
-            return x_next, states
+            if not tv_next:
+                return x_next, states
+            nxt = jnp.minimum(steps + 1, max_steps - 1)
+            return x_next, states, want_all_fn(
+                params, states, nxt, x_next, tv_next[0], labels,
+                scales > 0.0)
 
         def make_tick(mode: str):
             """Dense whole-pool programs (row_compaction=False baseline):
@@ -642,7 +693,8 @@ class DiffusionServingEngine:
             which contributes zero jit operand leaves, so their program
             signature is exactly the pre-text one."""
             def tick(params, states, steps, xs, tvals, labels, nulls,
-                     null_vecs, null_mask, txt, scales, cfg_ws, ab_t, ab_n):
+                     null_vecs, null_mask, txt, scales, cfg_ws, ab_t, ab_n,
+                     *tv_next):
                 if mode == "full":
                     y_c, y_u = backbone2_fn(params, xs, tvals, labels, nulls,
                                             null_vecs, null_mask, txt=txt)
@@ -652,7 +704,8 @@ class DiffusionServingEngine:
                 else:
                     y_c = y_u = jnp.zeros_like(xs)
                 return slot_step(params, states, steps, xs, tvals, labels,
-                                 scales, cfg_ws, ab_t, ab_n, y_c, y_u)
+                                 scales, cfg_ws, ab_t, ab_n, y_c, y_u,
+                                 *tv_next)
             return jax.jit(tick)
 
         def make_compact_tick(bucket: int):
@@ -660,10 +713,13 @@ class DiffusionServingEngine:
             backbone runs over the gathered `bucket`-row batch only; the
             scatter restores the S-row y_c / y_u layout (missing rows zero —
             they only reach branches the per-slot select discards).  All
-            index operands are traced, so this compiles once per bucket."""
+            index operands are traced, so this compiles once per bucket.
+            `tv_next`, on engines that plan on the device, sits between
+            `ab_n` and the row plan."""
             def tick(params, states, steps, xs, tvals, labels, nulls,
                      null_vecs, null_mask, txt, scales, cfg_ws, ab_t, ab_n,
-                     row_slot, row_uncond, row_dest):
+                     *rest):
+                *tv_next, row_slot, row_uncond, row_dest = rest
                 if bucket == 0:
                     y_c = y_u = jnp.zeros_like(xs)
                 else:
@@ -671,7 +727,8 @@ class DiffusionServingEngine:
                         params, xs, tvals, labels, nulls, null_vecs,
                         null_mask, txt, row_slot, row_uncond, row_dest)
                 return slot_step(params, states, steps, xs, tvals, labels,
-                                 scales, cfg_ws, ab_t, ab_n, y_c, y_u)
+                                 scales, cfg_ws, ab_t, ab_n, y_c, y_u,
+                                 *tv_next)
             return jax.jit(tick)
 
         # program builders are kept either way: repro.analysis.ir re-traces
@@ -685,12 +742,10 @@ class DiffusionServingEngine:
         else:
             self._ticks = {kind: make_tick(kind)
                            for kind in ("full", "cond", "skip")}
-        # fused plan pass: cond want + uncond want + trace metric in ONE
-        # jitted call — the TeaCache signal is computed over the whole slot
-        # batch outside vmap (repro.diffusion.pipeline.slot_want_fns), so a
-        # signal-policy pool pays one batched embed and one device sync per
-        # tick instead of per-slot singleton embeds and two syncs
-        self._want_all = jax.jit(slot_want_fns(cfg, self.policy, cfg_policy))
+        # the standalone want pass, for ticks with no carried plan (a
+        # session's first tick, and every tick whose admission refilled a
+        # slot: a fresh slot's metric needs its new noise's signal)
+        self._want_all = jax.jit(want_all_fn)
         # the pre-compile jit wrapper, kept for IR re-capture (warmup swaps
         # self._want_all for its Compiled executable)
         self._want_src = self._want_all
@@ -732,6 +787,12 @@ class DiffusionServingEngine:
         self._static_cfg_plan = (
             self._probe_static_plan(uncond_pol) if uncond_pol is not None
             else np.ones((max_steps,), bool))
+
+        # otherwise each tick program ends by planning the next tick (the
+        # fused want pass on its own outputs: slot_step's `tv_next`), and
+        # the session carries that plan
+        self._device_plan = (self._static_plan is None
+                             or self._static_cfg_plan is None)
 
         # host-side per-slot timestep tables, padded to max_steps (+1 for the
         # terminal alpha-bar = 1.0 that closes the DDIM update)
@@ -814,7 +875,8 @@ class DiffusionServingEngine:
     def _warmup_operands(self):
         """Operands shaped exactly like a live tick's: the 14-tuple every
         tick program takes (the model params first; the text-table dict is
-        empty on text-free engines — zero operand leaves), and the fused
+        empty on text-free engines — zero operand leaves), 15 with the next
+        tick's timesteps on engines that plan on the device, and the fused
         want pass's 7-tuple (shared prefixes, so warmup and IR capture
         trace the same shapes a session dispatches)."""
         S = self.slots
@@ -830,6 +892,8 @@ class DiffusionServingEngine:
         ab = jnp.full((S,), 0.5, jnp.float32)
         tick_args = (self.params, states, zi, xs, zf, zi, zi, nv, nm,
                      self._empty_txt(), zf, zf, ab, ab)
+        if self._device_plan:
+            tick_args += (zf,)                  # the next tick's timesteps
         want_args = (self.params, states, zi, xs, zf, zi, nm)
         return tick_args, want_args
 
@@ -881,7 +945,7 @@ class DiffusionServingEngine:
         args, want_args = self._warmup_operands()
         # the fused want pass also compiles on first use; without this a
         # state-dependent policy pays that compile inside its first live tick
-        if self._static_plan is None or self._static_cfg_plan is None:
+        if self._device_plan:
             if verify:
                 self._want_all, prof, ir = compile_program(
                     self._want_src, *want_args, key="want", want_ir=True)
@@ -976,7 +1040,7 @@ class DiffusionServingEngine:
             return self.program_ir
         from repro.obs.profiling import capture_ir
         args, want_args = self._warmup_operands()
-        if self._static_plan is None or self._static_cfg_plan is None:
+        if self._device_plan:
             self.program_ir["want"] = capture_ir(
                 self._want_src, *want_args, key="want")
         if self.text_enabled:
@@ -1102,26 +1166,32 @@ class DiffusionServingEngine:
                 self._null_mask[slot] = True
         self._scales[slot] = req.cfg_scale
         self._nsteps[slot] = req.num_steps
-        self._guided[slot] = req.guided
+        # guided exactly where the tick program blends (its float32 scale
+        # > 0), which is also how a tick program's own plan reads it
+        self._guided[slot] = self._scales[slot] > 0.0
 
-    def _plan_all(self, states, steps, xs, tvals):
+    def _plan_all(self, states, steps, xs, tvals, plan=None):
         """Per-slot (want_cond, want_uncond, metric) plan — before active
         masking; want_uncond is already masked by the per-slot guided flag.
 
         When BOTH branches admit a host-side static schedule the plan costs
         no device round trip at all (and metric is None — nothing dynamic
-        was measured).  Otherwise one fused jit call produces both want
-        vectors and the per-slot trace metric in a single device sync; a
-        branch that is static anyway is then overridden from its host plan
-        (the device predicate for it is mirrored, so this is equivalence-
-        preserving, not a behavior switch)."""
-        if self._static_plan is not None and self._static_cfg_plan is not None:
+        was measured).  Otherwise `plan` is the device triple the previous
+        tick program computed for this tick (its copy to the host started
+        at that tick's dispatch), or, when None, the fused want pass runs
+        here; a branch that is static anyway is then overridden from its
+        host plan (the device predicate for it is mirrored, so this is
+        equivalence-preserving, not a behavior switch)."""
+        if not self._device_plan:
             return (self._static_plan[steps],
                     self._static_cfg_plan[steps] & self._guided, None)
-        # repro-lint: disable-next-line=host-sync-in-hot-path -- THE one priced per-tick sync: fused want-pass, surcharged in plan cost
-        wc, wu, metric = jax.device_get(self._want_all(
-            self.params, states, jnp.asarray(steps), xs, jnp.asarray(tvals),
-            jnp.asarray(self._labels), jnp.asarray(self._guided)))
+        if plan is None:
+            plan = self._want_all(
+                self.params, states, jnp.asarray(steps), xs,
+                jnp.asarray(tvals), jnp.asarray(self._labels),
+                jnp.asarray(self._guided))
+        # repro-lint: disable-next-line=host-sync-in-hot-path -- the one per-tick plan read: a carried plan is already on the host; the want pass (first tick, admission ticks) waits its round trip here, surcharged in plan cost
+        wc, wu, metric = jax.device_get(plan)
         wc, wu = np.asarray(wc, bool), np.asarray(wu, bool)
         if self._static_plan is not None:
             wc = self._static_plan[steps]

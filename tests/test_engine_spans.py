@@ -173,6 +173,11 @@ def test_span_counts_match_the_tick_events(served):
         [len(ev.finished) for ev in events]
     assert [s["on_device"] for s in by_name["engine.plan"]] == \
         [int(ev.metric is not None) for ev in events]
+    # the previous tick program's plan, but on the first tick and on
+    # every tick whose admission refilled a slot
+    assert [s["carried"] for s in by_name["engine.plan"]] == \
+        [int(n > 0 and not ev.admitted) for n, ev in enumerate(events)]
+    assert 0 in [s["carried"] for s in by_name["engine.plan"][1:]]
     assert [s["bucket"] for s in by_name["engine.dispatch"]] == \
         [ev.rows_computed + ev.rows_padding for ev in events]
 
